@@ -40,8 +40,8 @@ single device dispatch, and the numpy/jnp oracles are untouched — the
 three-backend bit-equality invariant pins fused and staged semantics alike.
 
 The pallas kernels run interpret (CPU validation) or compiled
-(Mosaic/Triton) per the ONE flag resolved here: ``interpret=None`` asks
-``kernels.backend.default_interpret`` (capability-based), the resolved
+(Mosaic, on a TPU) per the ONE flag resolved here: ``interpret=None`` asks
+``kernels.backend.default_interpret``, the resolved
 bool re-judges fusion legality for the compiled lowering's VMEM extra
 (``reason_kind="mosaic-illegal"`` fallback, never a crash) and is handed
 to every kernel — kernels never re-resolve it.
@@ -84,7 +84,7 @@ from repro.core.optimizer import optimize_plan
 from repro.core.planner import (CrossStage, DataflowGroup, DataflowProgram,
                                 ExecutionPlan, FitProgram, FusedStage,
                                 OneHotStage, PackOutput, VocabLookupStage,
-                                build_plan_programs)
+                                build_plan_programs, kernel_vmem_limit)
 from repro.kernels import lanes
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
@@ -413,7 +413,8 @@ class CompiledPipeline:
         return kops.output_dataflow(inputs, tables, steps, terminals,
                                     po.dtype, pad_cols_to=po.pad_cols_to,
                                     block_rows=plan.row_tile,
-                                    interpret=self.interpret)
+                                    interpret=self.interpret,
+                                    vmem_limit_bytes=kernel_vmem_limit(plan))
 
     def _dataflow_steps(self, stage_ids, vocab_ids):
         """TileStep program + TableInput list for an apply-side slice
@@ -447,7 +448,8 @@ class CompiledPipeline:
                 po.dtype, po.pad_cols_to))
         return kops.group_dataflow(inputs, tables, steps, outs,
                                    block_rows=plan.row_tile,
-                                   interpret=self.interpret)
+                                   interpret=self.interpret,
+                                   vmem_limit_bytes=kernel_vmem_limit(plan))
 
     def _tile_steps(self, stage_ids) -> list[TileStep]:
         """Shared TileStep codegen for the fused apply/fit kernel bodies
@@ -478,15 +480,10 @@ class CompiledPipeline:
                               plan.buffers[b].hex_width)
                   for b in fp.source_buffers]
         steps = self._tile_steps(fp.stage_ids)
-        # partition the first-pos/count accumulators across the grid (the
-        # vocab-build HBM-bank pattern) once a single lane-padded block
-        # would be large: ~64K entries per partition keeps each (1, part)
-        # accumulator pair ~512 KiB of VMEM
-        partitions = max(1, -(-fp.capacity // 65536))
         return kops.fit_dataflow(inputs, steps, fp.in_buf, fp.capacity,
-                                 partitions=partitions,
                                  block_rows=plan.row_tile,
-                                 interpret=self.interpret)
+                                 interpret=self.interpret,
+                                 vmem_limit_bytes=kernel_vmem_limit(plan))
 
     def _build_apply(self) -> Callable:
         plan = self.plan
@@ -627,10 +624,11 @@ class CompiledPipeline:
                     out[vf.vocab_id] = fit_kernels[vf.vocab_id](
                         *(bufs[b] for b in fp.source_buffers))
                     continue
-                vals = bufs[vf.in_buf].reshape(-1)
+                vals = bufs[vf.in_buf]
                 # first-occurrence positions + counts (frequency filter)
-                out[vf.vocab_id] = (builds[vf.vocab_id](vals),
-                                    kref.vocab_counts_chunk(vals, vf.capacity))
+                out[vf.vocab_id] = (
+                    builds[vf.vocab_id](vals),
+                    kref.vocab_counts_chunk(vals.reshape(-1), vf.capacity))
             return out
 
         return fit_chunk

@@ -23,6 +23,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -55,6 +56,50 @@ def _mix32_jnp(x):
     x = x * jnp.uint32(0x846CA68B)
     x = x ^ (x >> 16)
     return x
+
+
+# Cephes ``logf`` minimax coefficients for log(1 + f), f in [sqrt(.5)-1,
+# sqrt(2)-1): log(1 + f) = f - f**2/2 + f**3 * P(f)
+_LOG_P = (7.0376836292e-2, -1.1514610310e-1, 1.1676998740e-1,
+          -1.2420140846e-1, 1.4249322787e-1, -1.6668057665e-1,
+          2.0000714765e-1, -2.4999993993e-1, 3.3333331174e-1)
+
+
+def _pow2_f32(e):
+    """2.0**e as float32 for int32 ``e``, clamped to the normal range."""
+    return jax.lax.bitcast_convert_type(
+        jnp.clip(e + 127, 1, 254) << 23, jnp.float32)
+
+
+def log1p_f32(x):
+    """float32 log(1 + x) within 1 ulp of the correctly rounded value, from
+    adds, multiplies and bit operations only.
+
+    The TPU's own ``log1p`` (XLA and Mosaic alike) is about 2.6e-4 off
+    numpy's in relative terms; this form is accurate on every backend.
+    1 + x = m * 2**k with m in [sqrt(.5), sqrt(2)) is split off the exponent
+    bits, log(m) comes from Cephes' polynomial, and the rounding error of
+    1 + x is added back as c / (1 + x).  ``m * 2**k - 1`` rebuilds u - 1
+    from the split so that no simplifier folds ``(1 + x) - 1`` to ``x``.
+    """
+    u = 1.0 + x
+    bits = jax.lax.bitcast_convert_type(u, jnp.int32)
+    k = (bits - 0x3F3504F3) >> 23
+    m = jax.lax.bitcast_convert_type(bits - (k << 23), jnp.float32)
+    f = m - 1.0
+    z = f * f
+    # rounding error of u (exact below 2**24; negligible beyond)
+    c = jnp.where(k < 25, x - (m * _pow2_f32(k) - 1.0), 0.0)
+    corr = (c * _pow2_f32(-k)) * (1.0 - f + z)  # ~ c / u
+    p = jnp.float32(_LOG_P[0])
+    for coef in _LOG_P[1:]:
+        p = p * f + jnp.float32(coef)
+    kf = k.astype(jnp.float32)
+    y = f * z * p + kf * jnp.float32(-2.12194440e-4) - 0.5 * z + corr
+    r = (f + y) + kf * jnp.float32(0.693359375)
+    r = jnp.where(u == jnp.inf, jnp.inf, r)
+    r = jnp.where(u == 0.0, -jnp.inf, r)
+    return jnp.where((u < 0.0) | (x != x), jnp.nan, r)
 
 
 @dataclasses.dataclass
@@ -135,7 +180,7 @@ class Logarithm(Operator):
         return np.log1p(x)
 
     def jnp_expr(self, x):
-        return jnp.log1p(x)
+        return log1p_f32(x.astype(jnp.float32)).astype(x.dtype)
 
     def validate(self, in_dtype):
         if not np.issubdtype(in_dtype, np.floating):

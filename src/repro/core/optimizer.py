@@ -183,7 +183,8 @@ def _merged_working_set(plan: ExecutionPlan, members) -> int:
     if plan.compiled_mode:
         # merged slices are judged with the same compiled-lowering extra
         # (lane padding + gather scratch) the per-output legality used
-        ws += compiled_extra_bytes(plan, stages, sources)
+        ws += compiled_extra_bytes(plan, stages, sources,
+                                   [po for po, _ in members])
     return ws
 
 

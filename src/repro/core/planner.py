@@ -45,9 +45,9 @@ DATAFLOW_BLOCK_ROWS = 256  # row-tile granularity of the fused dataflow kernels
 #   "stage-kind"      a sliced stage has no tile codegen
 #   "hbm-table"       a table / accumulator set is HBM-resident
 #   "budget"          the per-tile working set exceeds dataflow_vmem_budget
-#   "mosaic-illegal"  legal in interpret mode, but the compiled (Mosaic /
-#                     Triton) lowering's extra VMEM — lane-padded blocks and
-#                     banked-gather scratch — pushes the tile over budget
+#   "mosaic-illegal"  legal in interpret mode, but the compiled (Mosaic)
+#                     lowering's extra VMEM — lane-padded blocks, table
+#                     row padding, gather scratch — pushes it over budget
 FALLBACK_HEX_TERMINAL = "hex-terminal"
 FALLBACK_STAGE_KIND = "stage-kind"
 FALLBACK_HBM_TABLE = "hbm-table"
@@ -548,11 +548,18 @@ def packed_output_bytes(plan: ExecutionPlan, po: PackOutput,
 
 
 def compiled_extra_bytes(plan: ExecutionPlan, stages, sources,
-                         *, block_rows: Optional[int] = None) -> int:
-    """Extra per-tile VMEM the *compiled* (Mosaic/Triton) lowering holds on
-    top of the logical working set: lane-padding on every streamed buffer
-    tile and table, plus the banked-gather scratch each in-kernel lookup
-    materializes (``lanes.lane_gather`` broadcasts one bank per pass).
+                         pack_outputs=(), *,
+                         block_rows: Optional[int] = None) -> int:
+    """Extra VMEM the *compiled* (Mosaic) lowering holds on top of the
+    logical working set, counted the way the kernels lay memory out:
+
+    - lane padding of every streamed / produced tile and of each packed
+      output tile (double-buffered, like the logical tiles);
+    - row-layout padding of each VMEM-resident vocab table
+      (``lanes.table_rows``; the table itself is in the logical set once);
+    - the staging and gathered tiles of each in-kernel lookup
+      (``lanes.gather_scratch_bytes``).
+
     Interpret mode streams the logical widths, so this is zero there.
     """
     if block_rows is None:
@@ -562,12 +569,28 @@ def compiled_extra_bytes(plan: ExecutionPlan, stages, sources,
     for b in set(sources) | produced:
         spec = plan.buffers[b]
         extra_w = lanes.lane_pad(spec.width) - spec.width
-        pad += block_rows * spec.dtype.itemsize * extra_w * (spec.hex_width or 1)
+        pad += (2 * block_rows * spec.dtype.itemsize * extra_w
+                * (spec.hex_width or 1))
+    for po in pack_outputs:
+        out_w = sum(plan.buffers[b].width for b in po.buffers)
+        padded_w = -(-out_w // po.pad_cols_to) * po.pad_cols_to
+        pad += (2 * block_rows * po.dtype.itemsize
+                * (lanes.lane_pad(padded_w) - padded_w))
     for s in stages:
         if isinstance(s, VocabLookupStage):
-            pad += 4 * (lanes.lane_pad(s.capacity) - s.capacity)
-            pad += lanes.gather_scratch_bytes(block_rows, s.capacity)
+            pad += 4 * (lanes.table_rows(s.capacity) * lanes.LANE
+                        - s.capacity)
+            pad += lanes.gather_scratch_bytes(
+                block_rows, plan.buffers[s.in_buf].width)
     return pad
+
+
+def kernel_vmem_limit(plan: ExecutionPlan) -> int:
+    """Scoped-VMEM limit handed to Mosaic for the fused kernels: twice the
+    planner's budget, so whatever the legality pass admits compiles with
+    the same room again for Mosaic's own scratch (16 MiB at the default
+    budget — Mosaic's own default on v5e)."""
+    return 2 * plan.dataflow_vmem_budget
 
 
 def build_dataflow_program(plan: ExecutionPlan, po: PackOutput,
@@ -586,9 +609,10 @@ def build_dataflow_program(plan: ExecutionPlan, po: PackOutput,
     class (budget vs stage kind vs HBM table vs hex terminal).
 
     ``compiled`` (default: ``plan.compiled_mode``) judges the slice for
-    the compiled Pallas lowering: the lane-padded / gather-scratch extra
-    of ``compiled_extra_bytes`` is added, and a slice that fits the
-    logical budget but not the compiled one falls back "mosaic-illegal".
+    the compiled Pallas lowering: the padding / gather-scratch extra of
+    ``compiled_extra_bytes`` is added, and a slice that fits the logical
+    budget but not the compiled one falls back "mosaic-illegal".  The
+    fused kernels then compile under ``kernel_vmem_limit``.
     """
     if compiled is None:
         compiled = plan.compiled_mode
@@ -633,7 +657,7 @@ def build_dataflow_program(plan: ExecutionPlan, po: PackOutput,
                        f"budget {plan.dataflow_vmem_budget}",
                        FALLBACK_BUDGET)
     if compiled:
-        extra = compiled_extra_bytes(plan, stages, sources,
+        extra = compiled_extra_bytes(plan, stages, sources, [po],
                                      block_rows=block_rows)
         if working_set + extra > plan.dataflow_vmem_budget:
             return illegal(
@@ -651,15 +675,16 @@ def build_fit_program(plan: ExecutionPlan, vf: VocabFit,
     Legal programs lower decode + bound + first-occurrence/count build to
     a single row-tiled kernel, so the VMEM argument adds the build-side
     accumulators: two int32[capacity] tables (chunk first-pos + counts)
-    stay resident across the whole grid.  An HBM-placed vocab therefore
+    stay resident, once each, across the whole grid.  An HBM-placed vocab therefore
     falls back (its capacity is exactly what exceeded the table budget),
     as does any stage kind the fit tile codegen does not know or an
     over-budget working set — staged per vocab, never per pipeline;
     ``reason_kind`` names the fallback class either way.
 
     ``compiled`` (default: ``plan.compiled_mode``) additionally accounts
-    the lane-padded accumulator blocks and streamed-tile padding of the
-    compiled lowering; over the top is "mosaic-illegal".
+    the row-layout accumulator padding, the build's staging tile and the
+    streamed-tile padding of the compiled lowering; over the top is
+    "mosaic-illegal".
     """
     if compiled is None:
         compiled = plan.compiled_mode
@@ -694,7 +719,12 @@ def build_fit_program(plan: ExecutionPlan, vf: VocabFit,
     if compiled:
         extra = compiled_extra_bytes(plan, stages, sources,
                                      block_rows=block_rows)
-        extra += 2 * 4 * (lanes.lane_pad(vf.capacity) - vf.capacity)
+        # row-layout padding of both accumulators, plus the int32 staging
+        # tile the serialized build reads its values through
+        extra += 2 * 4 * (lanes.table_rows(vf.capacity) * lanes.LANE
+                          - vf.capacity)
+        extra += (block_rows * 4
+                  * lanes.lane_pad(plan.buffers[vf.in_buf].width))
         if working_set + extra > plan.dataflow_vmem_budget:
             return illegal(
                 f"compiled lowering needs {working_set + extra} bytes "

@@ -397,26 +397,6 @@ def top_instructions(hlo_text: str, n: int = 12) -> list[tuple]:
     return rows[:n]
 
 
-def xla_cost_analysis(compiled) -> dict:
-    """Normalized ``Compiled.cost_analysis()``.
-
-    Depending on JAX version this returns a dict or a list with one dict per
-    device/partition; normalize to a single flat dict (summing numeric
-    entries across list elements so multi-device results stay meaningful).
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, dict):
-        return ca
-    out: dict = {}
-    for entry in ca or []:
-        for k, v in entry.items():
-            if isinstance(v, (int, float)) and k in out:
-                out[k] += v
-            else:
-                out[k] = v
-    return out
-
-
 def analyze(hlo_text: str) -> dict:
     """Full trip-count-aware summary of a post-SPMD module (per device)."""
     model = HloCostModel(hlo_text)

@@ -1,14 +1,11 @@
-"""Zero-copy handoff from the ETL engine to the trainer (paper's P2P DMA).
+"""Handoff of packed batches from the ETL engine to the trainer.
 
-On a real TPU pod the ETL apply-program runs on the same mesh as the trainer,
-and its outputs are produced *already laid out* with the exact NamedSharding
-``train_step`` declares in ``in_shardings``.  The handoff is then a device-
-resident buffer passed by reference (and donated by the trainer) — no host
-staging, no reshard, no copy: the TPU statement of "the FPGA writes training-
-ready batches directly into GPU HBM".
-
-This module provides the placement helpers plus a host-fallback path
-(jax.device_put) used when the raw source lives in host memory.
+``put_packed`` places a packed batch on the trainer's devices, sharded
+along rows over the mesh's data axes (``batch_sharding``), so the batch
+already has the layout ``train_step`` declares in ``in_shardings``.  The
+trainer may then donate it (``jit_train_step(..., donate_batch=True)``).
+Whether this path avoids copies and reshards on a TPU has not been
+measured.
 """
 
 from __future__ import annotations
@@ -33,8 +30,7 @@ def donation_ready(batch: dict) -> bool:
 
     ``put_packed`` output always satisfies this; host numpy batches do not
     (XLA copies them on dispatch, so donation would be meaningless).  Pair
-    with ``jit_train_step(..., donate_batch=True)`` to complete the
-    zero-copy handoff.
+    with ``jit_train_step(..., donate_batch=True)``.
     """
     return all(isinstance(v, jax.Array) for v in batch.values())
 
@@ -43,8 +39,7 @@ def put_packed(batch: dict, sharding: Optional[NamedSharding]) -> dict:
     """Place a packed batch onto the mesh, sharded along rows (batch dim).
 
     The returned arrays are committed device buffers in the trainer's
-    declared layout — donation-ready: a ``donate_argnums`` train step can
-    alias their HBM instead of copying.
+    declared layout, so a ``donate_argnums`` train step may take them.
     """
     if sharding is None:
         return {k: jax.device_put(v) for k, v in batch.items()}

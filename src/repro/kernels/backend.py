@@ -3,16 +3,15 @@
 ``default_interpret`` is the ONE switch every kernel entry point resolves
 against (``interpret=None`` in the public wrappers and the raw factories
 alike): interpret mode runs the kernel body as traced JAX ops — the CPU
-validation harness — while compiled mode lowers through the backend's real
-Pallas pipeline.  Selection is by *capability*, not a TPU whitelist:
+validation harness — while compiled mode lowers through Mosaic.  The
+kernels use TPU memory spaces (VMEM residency, SMEM scalar staging), so
+the TPU is the only compiled target:
 
-- ``tpu``  -> Mosaic lowering exists          -> compiled (interpret=False)
-- ``gpu``  -> the Pallas Triton path exists   -> compiled (interpret=False)
-- anything else (cpu, unknown plugins)        -> interpret (interpret=True)
+- ``tpu``  -> Mosaic lowering exists  -> compiled (interpret=False)
+- anything else                       -> interpret (interpret=True)
 
 The resolved mode is logged exactly once per process so a silent fall-back
-to interpret mode (the bug this module fixes: GPU hosts used to interpret
-every kernel and throw the Triton path away) is visible in any log.
+to interpret mode is visible in any log.
 """
 
 from __future__ import annotations
@@ -25,15 +24,14 @@ import jax
 logger = logging.getLogger("repro.kernels")
 
 # jax.default_backend() -> the Pallas compiled lowering it can drive
-_COMPILED_TARGETS = {"tpu": "mosaic", "gpu": "triton"}
+_COMPILED_TARGETS = {"tpu": "mosaic"}
 
 _logged_mode = False
 
 
 def compiled_backend() -> Optional[str]:
     """Name of the compiled Pallas target for this process's default JAX
-    backend ("mosaic" | "triton"), or None when only interpret mode can
-    execute (CPU and unknown plugin backends)."""
+    backend ("mosaic"), or None when only interpret mode can execute."""
     return _COMPILED_TARGETS.get(jax.default_backend())
 
 
@@ -41,7 +39,7 @@ def default_interpret() -> bool:
     """Resolved interpret flag for every kernel whose caller passed None.
 
     False whenever a compiled Pallas target exists for the default backend
-    (TPU/Mosaic, GPU/Triton), True otherwise.  Logs the resolution once.
+    (TPU/Mosaic), True otherwise.  Logs the resolution once.
     """
     global _logged_mode
     target = compiled_backend()

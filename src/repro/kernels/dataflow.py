@@ -35,32 +35,26 @@ factories, in increasing order of fusion:
     The fit-phase sibling: the backward slice of one ``VocabFit`` — decode,
     bounding chains, joins — plus the chunk first-occurrence + count build
     as ONE row-tiled kernel.  The two int32 accumulators are the kernel
-    outputs, partitioned across grid dim 0 (the paper's "P HBM banks",
-    same structure as ``kernels/vocab.py``) and revisited by every row
-    tile of grid dim 1.  In interpret mode each partition builds with
-    whole-tile masked scatters (``.at[].min`` / ``.at[].add``); in
-    compiled mode — where scatter does not lower — the same masks guard a
-    RAW-serialized per-row update loop mirroring the staged build kernel
-    (dynamic scalar stores into the partition block, the paper's
-    RAW-limited II).  Both forms fold identical (position, count)
-    contributions with order-independent combiners (min / add), so the
-    modes are bit-identical by construction and the compiled-parity suite
-    pins it wherever a compiled backend exists.
+    outputs, held whole in VMEM in the row layout across the grid.  The
+    build is the paper's RAW-serialized loop: every value of a tile is
+    read as a scalar and folded into its accumulator entries by a row
+    read-modify-write (``kernels.lanes``), in compiled and interpret mode
+    alike.
 
 Vocabulary tables enter the dataflow kernel pre-resolved: the compiler folds
 the OOV rule (``miss -> n_unique``) into the table before the call, so the
-in-kernel lookup is a pure banked lane gather (``kernels.lanes.lane_gather``
-— no flat reshape, no whole-table broadcast).
+in-kernel lookup is a pure gather (``kernels.lanes.lane_gather``) from a
+table resident whole in VMEM in the row layout (``(capacity/128, 128)``).
 
-Tiling: every memory block is lane-aligned — source, table, and packed
-output blocks are padded up to multiples of 128 lanes host-side (padding
-lanes carry zeros and are sliced off in-kernel / on return), block rows are
+Tiling: every streamed block is lane-aligned — source and packed output
+blocks are padded up to multiples of 128 lanes host-side (padding lanes
+carry zeros and are sliced off in-kernel / on return), block rows are
 multiples of 8 sublanes, and the grid streams row blocks — the paper's
 batch-of-rows FIFO granularity, in the shape Mosaic actually tiles.
 
 ``interpret=None`` on every factory resolves through
-``kernels.backend.default_interpret`` (compiled wherever a Mosaic/Triton
-target exists, interpret otherwise); passing an explicit bool pins the mode.
+``kernels.backend.default_interpret`` (compiled on a TPU, interpret
+otherwise); passing an explicit bool pins the mode.
 """
 
 from __future__ import annotations
@@ -73,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import lanes
 from repro.kernels.backend import default_interpret
@@ -84,6 +79,13 @@ def _round_up(x: int, m: int) -> int:
 
 def _resolve_interpret(interpret) -> bool:
     return default_interpret() if interpret is None else bool(interpret)
+
+
+def _compiler_params(vmem_limit_bytes: Optional[int]):
+    """Mosaic's scoped-VMEM limit for a fused kernel (None: its default)."""
+    if vmem_limit_bytes is None:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=int(vmem_limit_bytes))
 
 
 # ---------------------------------------------------------------------------
@@ -232,16 +234,11 @@ class TileStep:
     table: int = -1
 
 
-def _row_tile_sources(inputs, srcs, br: int, rp: int,
-                      partitioned: bool = False):
+def _row_tile_sources(inputs, srcs, br: int, rp: int):
     """Pad each raw source to the row-tile multiple and a lane-multiple
     width, and emit its BlockSpec (hex sources are digit-major 3-d; the
     digit axis is not tiled).  The kernel slices each tile back to its
-    natural width, so padding lanes never enter the step program.
-
-    ``partitioned`` emits index maps for the fit kernel's 2-d grid
-    ``(partitions, row_tiles)``: every partition re-streams all row tiles.
-    """
+    natural width, so padding lanes never enter the step program."""
     rows = srcs[0].shape[1] if inputs[0].hex_width else srcs[0].shape[0]
     padded_srcs, in_specs = [], []
     for inp, x in zip(inputs, srcs):
@@ -249,15 +246,12 @@ def _row_tile_sources(inputs, srcs, br: int, rp: int,
         if inp.hex_width:
             padded_srcs.append(
                 jnp.pad(x, ((0, 0), (0, rp - rows), (0, wp - inp.width))))
-            imap = ((lambda p, r: (0, r, 0)) if partitioned
-                    else (lambda r: (0, r, 0)))
-            in_specs.append(pl.BlockSpec((inp.hex_width, br, wp), imap))
+            in_specs.append(pl.BlockSpec((inp.hex_width, br, wp),
+                                         lambda r: (0, r, 0)))
         else:
             padded_srcs.append(
                 jnp.pad(x, ((0, rp - rows), (0, wp - inp.width))))
-            imap = ((lambda p, r: (r, 0)) if partitioned
-                    else (lambda r: (r, 0)))
-            in_specs.append(pl.BlockSpec((br, wp), imap))
+            in_specs.append(pl.BlockSpec((br, wp), lambda r: (r, 0)))
     return padded_srcs, in_specs
 
 
@@ -269,15 +263,15 @@ def _load_source_env(inputs, src_refs) -> dict:
     return env
 
 
-def _pad_tables(tables, tbls):
-    """Lane-pad each (1, capacity) resolved table and emit its BlockSpec."""
-    padded, specs = [], []
+def _table_layout(tables, tbls):
+    """Each ``(1, capacity)`` resolved table in the row layout
+    (``lanes.to_rows``), resident whole in VMEM for the entire grid: one
+    copy in, no per-step re-fetch, no second pipeline buffer."""
+    laid = []
     for t, a in zip(tables, tbls):
         assert a.shape == (1, t.capacity), (a.shape, t.capacity)
-        cp = lanes.lane_pad(t.capacity)
-        padded.append(jnp.pad(a, ((0, 0), (0, cp - t.capacity))))
-        specs.append(pl.BlockSpec((1, cp), lambda r: (0, 0)))
-    return padded, specs
+        laid.append(lanes.to_rows(a))
+    return laid, [pl.BlockSpec(memory_space=pltpu.VMEM) for _ in tables]
 
 
 def _run_tile_steps(env: dict, steps, tbl_refs, capacities):
@@ -288,10 +282,9 @@ def _run_tile_steps(env: dict, steps, tbl_refs, capacities):
         elif st.kind == "join":
             env[st.out] = st.fn(env[st.args[0]], env[st.args[1]])
         elif st.kind == "lookup":
-            tbl = tbl_refs[st.table][...]  # (1, lane_pad(capacity)), resolved
-            x = env[st.args[0]]
-            safe = jnp.clip(x, 0, capacities[st.table] - 1)
-            env[st.out] = lanes.lane_gather(tbl, safe)
+            # row-layout, OOV-resolved table ref
+            safe = jnp.clip(env[st.args[0]], 0, capacities[st.table] - 1)
+            env[st.out] = lanes.lane_gather(tbl_refs[st.table], safe)
         else:
             raise NotImplementedError(st.kind)
 
@@ -302,7 +295,8 @@ def make_output_dataflow(inputs: Sequence[StreamInput],
                          terminals: Sequence[tuple],
                          out_dtype, *, pad_cols_to: int = 1,
                          block_rows: int = 256,
-                         interpret: Optional[bool] = None):
+                         interpret: Optional[bool] = None,
+                         vmem_limit_bytes: Optional[int] = None):
     """Build fn(*sources, *tables) -> packed [rows, padded(sum widths)].
 
     ``terminals`` is the ordered list of ``(buffer_name, width)`` pairs the
@@ -336,7 +330,7 @@ def make_output_dataflow(inputs: Sequence[StreamInput],
         br = min(block_rows, _round_up(rows, 8))
         rp = _round_up(rows, br)
         padded_srcs, in_specs = _row_tile_sources(inputs, srcs, br, rp)
-        padded_tbls, tbl_specs = _pad_tables(tables, tbls)
+        padded_tbls, tbl_specs = _table_layout(tables, tbls)
         out = pl.pallas_call(
             kernel,
             grid=(rp // br,),
@@ -344,6 +338,7 @@ def make_output_dataflow(inputs: Sequence[StreamInput],
             out_specs=pl.BlockSpec((br, lane_padded), lambda r: (r, 0)),
             out_shape=jax.ShapeDtypeStruct((rp, lane_padded), out_dtype),
             interpret=interpret,
+            compiler_params=_compiler_params(vmem_limit_bytes),
         )(*padded_srcs, *padded_tbls)
         return out[:rows, :padded]
 
@@ -369,7 +364,8 @@ def make_group_dataflow(inputs: Sequence[StreamInput],
                         steps: Sequence[TileStep],
                         outputs: Sequence[GroupOutput], *,
                         block_rows: int = 256,
-                        interpret: Optional[bool] = None):
+                        interpret: Optional[bool] = None,
+                        vmem_limit_bytes: Optional[int] = None):
     """Build fn(*sources, *tables) -> tuple of packed arrays, one per output.
 
     The grouped form of ``make_output_dataflow``: the merged backward slice
@@ -414,7 +410,7 @@ def make_group_dataflow(inputs: Sequence[StreamInput],
         br = min(block_rows, _round_up(rows, 8))
         rp = _round_up(rows, br)
         padded_srcs, in_specs = _row_tile_sources(inputs, srcs, br, rp)
-        padded_tbls, tbl_specs = _pad_tables(tables, tbls)
+        padded_tbls, tbl_specs = _table_layout(tables, tbls)
         outs = pl.pallas_call(
             kernel,
             grid=(rp // br,),
@@ -424,6 +420,7 @@ def make_group_dataflow(inputs: Sequence[StreamInput],
             out_shape=[jax.ShapeDtypeStruct((rp, lp), g.out_dtype)
                        for g, lp in zip(outputs, lane_paddeds)],
             interpret=interpret,
+            compiler_params=_compiler_params(vmem_limit_bytes),
         )(*padded_srcs, *padded_tbls)
         return tuple(o[:rows, :p] for o, p in zip(outs, paddeds))
 
@@ -440,52 +437,38 @@ ABSENT32 = 2 ** 31 - 1  # matches kernels.vocab / kernels.ref chunk sentinel
 def make_fit_dataflow(inputs: Sequence[StreamInput],
                       steps: Sequence[TileStep],
                       value_buf: str, capacity: int, *,
-                      partitions: int = 1, block_rows: int = 256,
+                      block_rows: int = 256,
                       interpret: Optional[bool] = None,
-                      build_form: str = "auto"):
+                      vmem_limit_bytes: Optional[int] = None):
     """Build fn(*sources) -> (first_pos int32[capacity], counts int32[capacity]).
 
-    One ``pallas_call`` over grid ``(partitions, row_tiles)``: row tiles of
-    every raw source stream through the ``TileStep`` chain (map/join only —
-    lookups cannot precede a fit), and each table partition accumulates the
-    chunk first-occurrence positions and occurrence counts of its value
-    range into a lane-padded VMEM block revisited by every row tile (the
-    paper's "P HBM banks"; partitions re-scan the stream in parallel, the
-    P-fold pass ``kernels/vocab.py`` and ``embedding_bag`` already use).
-    Semantics match the staged path exactly: positions are global row-major
-    flat offsets over the unpadded chunk, ``ABSENT32`` marks values absent
-    from the chunk, counts sum every occurrence (the frequency-filter
-    input), and negative / out-of-capacity values drop.
+    One ``pallas_call`` over the row tiles: row tiles of every raw source
+    stream through the ``TileStep`` chain (map/join only — lookups cannot
+    precede a fit), and the chunk first-occurrence positions and
+    occurrence counts accumulate into two row-layout tables
+    (``lanes.to_rows``) resident whole in VMEM across the grid.
+    Semantics match the staged path exactly: positions are global
+    row-major flat offsets over the unpadded chunk, ``ABSENT32`` marks
+    values absent from the chunk, counts sum every occurrence (the
+    frequency-filter input), and negative / out-of-capacity values drop.
 
-    The per-partition update has two Mosaic-equivalent forms selected by
-    the resolved ``interpret`` flag: whole-tile masked scatters
-    (``.at[].min`` / ``.at[].add``) in interpret mode, and the staged build
-    kernel's RAW-serialized scalar-store loop in compiled mode (scatter
-    does not lower under Mosaic).  Both fold identical contributions with
-    order-independent combiners, so the outputs are bit-identical; the
-    compiled-parity suite pins this on hardware, and ``build_form`` lets
-    CPU tests pin it too: "auto" selects by the resolved interpret flag,
-    "scatter" / "serial" force one form (the serial form also runs under
-    interpret mode, where both forms must agree bit-for-bit).
+    The update is the paper's RAW-serialized build: each value of the
+    tile is read as a scalar (``lanes.for_each_row``) and folded into its
+    accumulator entries with one row read-modify-write each
+    (``lanes.update_entry``).  A dropped value rewrites its row unchanged,
+    so the loop has no data-dependent branch.  Interpret mode runs the
+    same body, so the modes agree bit for bit.
     """
-    if build_form not in ("auto", "scatter", "serial"):
-        raise ValueError(f"unknown build_form {build_form!r}")
     inputs = list(inputs)
     steps = list(steps)
     interpret = _resolve_interpret(interpret)
-    serial_build = (build_form == "serial"
-                    or (build_form == "auto" and not interpret))
     n_src = len(inputs)
-    partitions = max(int(partitions), 1)
-    part = -(-capacity // partitions)       # logical values per partition
-    part_pad = lanes.lane_pad(part)         # lane-padded block width
+    acc_rows = lanes.table_rows(capacity)
 
     def kernel(*refs, n_rows: int):
         src_refs, fp_ref, cnt_ref = refs[:n_src], refs[-2], refs[-1]
-        p = pl.program_id(0)
-        lo = p * part
 
-        @pl.when(pl.program_id(1) == 0)
+        @pl.when(pl.program_id(0) == 0)
         def _init():
             fp_ref[...] = jnp.full(fp_ref.shape, ABSENT32, fp_ref.dtype)
             cnt_ref[...] = jnp.zeros(cnt_ref.shape, cnt_ref.dtype)
@@ -500,63 +483,45 @@ def make_fit_dataflow(inputs: Sequence[StreamInput],
                 raise NotImplementedError(st.kind)
         vals = env[value_buf]
         br, width = vals.shape
-        row0 = pl.program_id(1) * br
+        row0 = pl.program_id(0) * br
 
-        if not serial_build:
-            # whole-tile masked scatter into this partition's block
-            row = row0 + jax.lax.broadcasted_iota(jnp.int32, vals.shape, 0)
-            col = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
-            local = vals - lo
-            ok = ((row < n_rows) & (vals >= 0) & (vals < capacity)
-                  & (local >= 0) & (local < part))
-            pos = jnp.where(ok, row * width + col, ABSENT32).reshape(-1)
-            idx = jnp.where(ok, local, 0).reshape(-1)  # masked -> no-ops
-            one = jnp.where(ok, 1, 0).astype(jnp.int32).reshape(-1)
-            fp_ref[...] = fp_ref[...].at[0, idx].min(pos)
-            cnt_ref[...] = cnt_ref[...].at[0, idx].add(one)
-        else:
-            # Mosaic-legal form: serial per-row scan with dynamic scalar
-            # stores (the staged vocab build's RAW-serialized II); min/add
-            # are order-independent, so this folds the exact same values
-            def body(r, _):
-                gr = row0 + r
-                for c in range(width):  # static lane offset per column
-                    v = vals[r, c]
-                    local = v - lo
+        def row_fn(r, at):
+            gr = row0 + r
 
-                    @pl.when((gr < n_rows) & (v >= 0) & (v < capacity)
-                             & (local >= 0) & (local < part))
-                    def _upd(local=local, pos=gr * width + c):
-                        fp_ref[0, local] = jnp.minimum(fp_ref[0, local], pos)
-                        cnt_ref[0, local] = cnt_ref[0, local] + 1
+            def col(c, carry):
+                v = at(c)
+                ok = (gr < n_rows) & (v >= 0) & (v < capacity)
+                slot = jnp.where(ok, v, 0)
+                pos = jnp.where(ok, gr * width + c, ABSENT32)
+                lanes.update_entry(fp_ref, slot,
+                                   lambda row: jnp.minimum(row, pos))
+                lanes.update_entry(cnt_ref, slot,
+                                   lambda row: row + ok.astype(row.dtype))
+                return carry
 
-                return 0
+            jax.lax.fori_loop(0, width, col, 0)
 
-            jax.lax.fori_loop(0, br, body, 0)
+        pl.run_scoped(
+            lambda stage_ref, smem_ref: lanes.for_each_row(
+                vals, row_fn, stage_ref, smem_ref),
+            *lanes.scalar_scratch(br, width))
 
     def run(*srcs):
         assert len(srcs) == n_src, (len(srcs), n_src)
         rows = srcs[0].shape[1] if inputs[0].hex_width else srcs[0].shape[0]
         br = min(block_rows, _round_up(rows, 8))
         rp = _round_up(rows, br)
-        padded_srcs, in_specs = _row_tile_sources(
-            inputs, srcs, br, rp, partitioned=True)
+        padded_srcs, in_specs = _row_tile_sources(inputs, srcs, br, rp)
+        acc = jax.ShapeDtypeStruct((acc_rows, lanes.LANE), jnp.int32)
         fp, cnt = pl.pallas_call(
             functools.partial(kernel, n_rows=rows),
-            grid=(partitions, rp // br),
+            grid=(rp // br,),
             in_specs=in_specs,
-            out_specs=[pl.BlockSpec((1, part_pad), lambda p, r: (0, p)),
-                       pl.BlockSpec((1, part_pad), lambda p, r: (0, p))],
-            out_shape=[
-                jax.ShapeDtypeStruct((1, partitions * part_pad), jnp.int32),
-                jax.ShapeDtypeStruct((1, partitions * part_pad), jnp.int32)],
+            out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 2,
+            out_shape=[acc, acc],
             interpret=interpret,
+            compiler_params=_compiler_params(vmem_limit_bytes),
         )(*padded_srcs)
-        # un-interleave the lane padding: block p holds logical values
-        # [p*part, (p+1)*part) in its first ``part`` lanes
-        def unpad(t):
-            t = t.reshape(partitions, part_pad)[:, :part].reshape(-1)
-            return t[:capacity]
-        return unpad(fp), unpad(cnt)
+        return lanes.from_rows(fp, capacity), lanes.from_rows(cnt, capacity)
 
     return run

@@ -33,8 +33,9 @@ Block shapes are hardware-tiled: the embedding ``dim`` is lane-padded to a
 ``nnz`` lane-padded with ``-1`` sentinels and the kernel slices them to the
 sublane-padded ``nnz`` the 3-D rows tile uses, and partition row counts are
 sublane-padded (padded rows are unreachable: indices are bounded by the
-vocab and masked in-kernel).  The row gathers are reshape-free 2-D-indexed
-``jnp.take`` along the row axis — the gather shape Mosaic's rule accepts.
+vocab and masked in-kernel).  The row gather reads each index as a scalar
+and copies its row with a dynamic sublane index (``kernels.lanes``), the
+form Mosaic lowers; Mosaic has no vector gather across vregs.
 
 ``interpret=None`` resolves through ``kernels.backend.default_interpret``.
 """
@@ -91,8 +92,31 @@ def _pad_batch(idx, block_batch: int):
     return idx, bb, bp, nnz_lane
 
 
-def _gather_kernel(idx_ref, tbl_ref, rows_ref, *, part_rows: int,
-                   nnz_sub: int):
+def _gather_pass(local, src_ref, rows_ref):
+    """``rows_ref[b, k] = src_ref[local[b, k]]`` wherever ``local[b, k] >=
+    0``; other entries keep their value.  Each entry is read as a scalar
+    (``lanes.for_each_row``) and copies one row with a dynamic sublane
+    index — the row gather Mosaic lowers."""
+    bb, nnz = local.shape
+
+    def row_fn(b, at):
+        def col(k, carry):
+            v = at(k)
+            ok = v >= 0
+            row = src_ref[pl.ds(jnp.where(ok, v, 0), 1), :]
+            cur = rows_ref[b, pl.ds(k, 1), :]
+            rows_ref[b, pl.ds(k, 1), :] = jnp.where(ok, row, cur)
+            return carry
+
+        jax.lax.fori_loop(0, nnz, col, 0)
+
+    pl.run_scoped(
+        lambda stage_ref, smem_ref: lanes.for_each_row(
+            local, row_fn, stage_ref, smem_ref),
+        *lanes.scalar_scratch(bb, nnz))
+
+
+def _gather_kernel(idx_ref, tbl_ref, rows_ref, *, part_rows: int, nnz: int):
     """One table-partition pass: write rows for in-partition indices."""
     p = pl.program_id(1)
     lo = p * part_rows
@@ -101,13 +125,10 @@ def _gather_kernel(idx_ref, tbl_ref, rows_ref, *, part_rows: int,
     def _init():
         rows_ref[...] = jnp.zeros(rows_ref.shape, rows_ref.dtype)
 
-    idx = idx_ref[...][:, :nnz_sub]  # (bb, nnz_sub)
+    idx = idx_ref[...][:, :nnz]
     local = idx - lo
     inb = (local >= 0) & (local < part_rows) & (idx >= 0)
-    safe = jnp.where(inb, local, 0)
-    tbl = tbl_ref[...]  # (part_rows, dim_pad)
-    got = jnp.take(tbl, safe, axis=0)  # (bb, nnz_sub, dim_pad), reshape-free
-    rows_ref[...] = jnp.where(inb[..., None], got, rows_ref[...])
+    _gather_pass(jnp.where(inb, local, -1), tbl_ref, rows_ref)
 
 
 def embedding_bag(table, indices, *, partitions: int = 1, block_batch: int = 128,
@@ -127,7 +148,7 @@ def embedding_bag(table, indices, *, partitions: int = 1, block_batch: int = 128
     idx, bb, bp, nnz_lane = _pad_batch(indices, block_batch)
 
     rows = pl.pallas_call(
-        functools.partial(_gather_kernel, part_rows=part, nnz_sub=nnz_sub),
+        functools.partial(_gather_kernel, part_rows=part, nnz=nnz),
         grid=(bp // bb, parts),
         in_specs=[
             pl.BlockSpec((bb, nnz_lane), lambda b, p: (b, 0)),
@@ -141,18 +162,17 @@ def embedding_bag(table, indices, *, partitions: int = 1, block_batch: int = 128
 
 
 def _cache_gather_kernel(slot_ref, cache_ref, rows_ref, *, cache_rows: int,
-                         nnz_sub: int):
-    """Single dense pass over the (VMEM-resident) cache: the hot path."""
-    slot = slot_ref[...][:, :nnz_sub]
+                         nnz: int):
+    """Single pass over the (VMEM-resident) cache, the hot path: zero the
+    rows tile, then fill hot entries from the cache."""
+    rows_ref[...] = jnp.zeros(rows_ref.shape, rows_ref.dtype)
+    slot = slot_ref[...][:, :nnz]
     inb = (slot >= 0) & (slot < cache_rows)
-    safe = jnp.where(inb, slot, 0)
-    cache = cache_ref[...]
-    got = jnp.take(cache, safe, axis=0)
-    rows_ref[...] = jnp.where(inb[..., None], got, 0)
+    _gather_pass(jnp.where(inb, slot, -1), cache_ref, rows_ref)
 
 
 def _two_level_kernel(slot_ref, cold_ref, cache_ref, tbl_ref, rows_ref, *,
-                      part_rows: int, cache_rows: int, nnz_sub: int):
+                      part_rows: int, cache_rows: int, nnz: int):
     """Grid dim 1: step 0 = cache pass, steps 1..P = table partition passes.
 
     Hot entries (slot >= 0) resolve from the cache and shadow any cold id;
@@ -162,26 +182,19 @@ def _two_level_kernel(slot_ref, cold_ref, cache_ref, tbl_ref, rows_ref, *,
     p = pl.program_id(1)
 
     @pl.when(p == 0)
-    def _cache_pass():
-        slot = slot_ref[...][:, :nnz_sub]
-        inb = (slot >= 0) & (slot < cache_rows)
-        safe = jnp.where(inb, slot, 0)
-        cache = cache_ref[...]
-        got = jnp.take(cache, safe, axis=0)
-        rows_ref[...] = jnp.where(inb[..., None], got, 0)
+    def _cache():
+        _cache_gather_kernel(slot_ref, cache_ref, rows_ref,
+                             cache_rows=cache_rows, nnz=nnz)
 
     @pl.when(p > 0)
     def _table_pass():
         lo = (p - 1) * part_rows
-        cold = cold_ref[...][:, :nnz_sub]
+        cold = cold_ref[...][:, :nnz]
         local = cold - lo
         # hot entries already resolved from the cache: slot wins over cold
         inb = ((local >= 0) & (local < part_rows) & (cold >= 0)
-               & (slot_ref[...][:, :nnz_sub] < 0))
-        safe = jnp.where(inb, local, 0)
-        tbl = tbl_ref[...]
-        got = jnp.take(tbl, safe, axis=0)
-        rows_ref[...] = jnp.where(inb[..., None], got, rows_ref[...])
+               & (slot_ref[...][:, :nnz] < 0))
+        _gather_pass(jnp.where(inb, local, -1), tbl_ref, rows_ref)
 
 
 def embedding_bag_cached(table, cache, slot_idx, cold_idx=None, *,
@@ -216,7 +229,7 @@ def embedding_bag_cached(table, cache, slot_idx, cold_idx=None, *,
     if cold_idx is None:
         rows = pl.pallas_call(
             functools.partial(_cache_gather_kernel, cache_rows=cache_rows,
-                              nnz_sub=nnz_sub),
+                              nnz=nnz),
             grid=(bp // bb,),
             in_specs=[
                 pl.BlockSpec((bb, nnz_lane), lambda b: (b, 0)),
@@ -232,7 +245,7 @@ def embedding_bag_cached(table, cache, slot_idx, cold_idx=None, *,
     cold, _, _, _ = _pad_batch(cold_idx, block_batch)
     rows = pl.pallas_call(
         functools.partial(_two_level_kernel, part_rows=part,
-                          cache_rows=cache_rows, nnz_sub=nnz_sub),
+                          cache_rows=cache_rows, nnz=nnz),
         grid=(bp // bb, parts + 1),
         in_specs=[
             pl.BlockSpec((bb, nnz_lane), lambda b, p: (b, 0)),

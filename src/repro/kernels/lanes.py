@@ -2,38 +2,52 @@
 
 Mosaic (TPU) tiles vectors as (8 sublanes x 128 lanes); memory blocks whose
 minor dimension is not a multiple of 128 — or constructs like 1-D iota,
-lane-collapsing reshapes, and flat dynamic gathers — do not lower.  The
-kernels therefore share one vocabulary of lane-safe building blocks:
+lane-collapsing reshapes, and flat dynamic gathers — do not lower, and its
+vector gather (``take_along_axis``) only permutes within one vreg.  The
+kernels therefore share one vocabulary of Mosaic-legal building blocks:
 
 - ``lane_pad`` / ``sublane_pad``: round widths up to the hardware tile.
-- ``lane_gather``: gather ``tbl[0, idx]`` for a 2-D index tile without any
-  1-D reshape: the table tile is broadcast across sublanes (bank by bank,
-  so the broadcast operand stays VMEM-bounded) and gathered along lanes
-  with ``take_along_axis`` — the shape Mosaic's dynamic-gather rule and
-  Triton's vectorized loads both accept.  Interpret mode evaluates the same
-  jnp ops, so both modes compute bit-identical values by construction.
+- **row layout**: a flat int32 table of ``capacity`` entries travels as a
+  ``(table_rows(capacity), 128)`` array, entry ``v`` at ``[v >> 7, v & 127]``
+  (``to_rows`` / ``from_rows``).  No sublane padding is wasted, and one
+  entry is reachable with a dynamic *sublane* index, which Mosaic lowers.
+- ``for_each_row``: visit an in-kernel int32 tile row by row with its
+  entries as scalars.  The tile is staged VMEM -> SMEM in chunks of
+  ``SMEM_ROWS`` rows (vector registers have no dynamic scalar extract;
+  SMEM has dynamic scalar loads).
+- ``lane_gather``: ``out[r, c] = table[idx[r, c]]`` against a row-layout
+  table ref: per entry one dynamic-sublane row load and a one-hot lane
+  reduce.  Cost is O(entries), independent of the table capacity.
+- ``update_entry``: read-modify-write one entry of a row-layout ref (the
+  fit build's scatter-min / scatter-add, serialized like the paper's
+  RAW-limited vocab build).
 - ``onehot_lanes``: the in-kernel one-hot. The operator-level expression
   (``operators.OneHot.jnp_expr``) collapses the depth axis with a reshape
   that merges into the lane dimension — illegal under Mosaic — so the tile
   codegen emits this per-column concat form instead: same values, lane
   concatenation only, iota only in its 2-D broadcasted form.
 - ``gather_scratch_bytes``: the planner's VMEM account of one in-kernel
-  ``lane_gather`` (bank broadcast + gathered bank), used by the
-  compiled-mode legality pass (``mosaic-illegal`` fallback).
+  ``lane_gather`` / fit build (staging and gathered tiles).
+
+Interpret mode evaluates the very same kernel bodies, so both modes compute
+bit-identical values by construction.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128      # minor-dim tile of a TPU vreg
 SUBLANE = 8     # second-minor tile (float32/int32)
+LANE_BITS = 7   # log2(LANE): row = v >> LANE_BITS, lane = v & (LANE - 1)
 
-# lanes per bank of the in-kernel table gather: bounds the broadcast
-# operand of lane_gather to (block_rows, GATHER_BANK) whatever the table
-# capacity, at the cost of one masked pass per bank
-GATHER_BANK = 2048
+# rows of an int32 tile staged into SMEM at a time by ``for_each_row``;
+# bounds the SMEM scratch to SMEM_ROWS x lane_pad(width) words whatever
+# the row tile
+SMEM_ROWS = 64
 
 
 def round_up(x: int, m: int) -> int:
@@ -50,37 +64,115 @@ def sublane_pad(w: int) -> int:
     return round_up(max(int(w), 1), SUBLANE)
 
 
-def lane_gather(tbl, idx):
-    """``out[r, c] = tbl[0, idx[r, c]]`` with lane-aligned ops only.
+# ---------------------------------------------------------------------------
+# row layout of flat tables
+# ---------------------------------------------------------------------------
 
-    ``tbl``: (1, C); ``idx``: int (rows, w), every entry in [0, C).
-    Each index hits exactly one bank, so the masked bank passes compose to
-    the exact gather (no accumulation, last write wins per element).
-    """
-    rows = idx.shape[0]
-    c = tbl.shape[-1]
-    if c <= GATHER_BANK:
-        bank = jnp.broadcast_to(tbl, (rows, c))
-        return jnp.take_along_axis(bank, idx, axis=1)
-    acc = jnp.zeros(idx.shape, tbl.dtype)
-    for b in range(0, c, GATHER_BANK):
-        bw = min(GATHER_BANK, c - b)
-        local = idx - b
-        inb = (local >= 0) & (local < bw)
-        safe = jnp.where(inb, local, 0)
-        bank = jnp.broadcast_to(tbl[:, b:b + bw], (rows, bw))
-        got = jnp.take_along_axis(bank, safe, axis=1)
-        acc = jnp.where(inb, got, acc)
-    return acc
+def table_rows(capacity: int) -> int:
+    """Rows of the (rows, LANE) layout holding ``capacity`` entries."""
+    return sublane_pad(-(-int(capacity) // LANE))
 
 
-def gather_scratch_bytes(block_rows: int, capacity: int,
-                         itemsize: int = 4) -> int:
-    """VMEM bytes one in-kernel ``lane_gather`` holds live per tile: the
-    broadcast bank plus the gathered bank (the accumulator is the output
-    tile the working set already counts)."""
-    bank = min(lane_pad(capacity), GATHER_BANK)
-    return 2 * block_rows * bank * itemsize
+def to_rows(flat):
+    """Flat ``[capacity]`` (or ``(1, capacity)``) -> ``(table_rows, LANE)``;
+    padding entries are zero and never addressed."""
+    flat = flat.reshape(-1)
+    n = table_rows(flat.shape[0]) * LANE
+    return jnp.pad(flat, (0, n - flat.shape[0])).reshape(-1, LANE)
+
+
+def from_rows(t, capacity: int):
+    """``(rows, LANE)`` layout -> flat ``[capacity]``."""
+    return t.reshape(-1)[:capacity]
+
+
+# ---------------------------------------------------------------------------
+# in-kernel scalar access
+# ---------------------------------------------------------------------------
+
+def scalar_scratch(rows: int, w: int) -> list:
+    """Scratch ``for_each_row`` needs for a (rows, w) tile: a VMEM staging
+    tile and an SMEM chunk.  Callers allocate it in their own
+    ``pl.run_scoped`` (one scope per kernel region; interpret mode cannot
+    discharge nested scopes that read SMEM in a loop)."""
+    return [pltpu.VMEM((rows, lane_pad(w)), jnp.int32),
+            pltpu.SMEM((min(SMEM_ROWS, rows), lane_pad(w)), jnp.int32)]
+
+
+def for_each_row(vals, row_fn, stage_ref, smem_ref) -> None:
+    """Call ``row_fn(r, at)`` for every row ``r`` of the in-kernel int32
+    tile ``vals`` (rows, w), in row order; ``at(c)`` reads ``vals[r, c]``
+    as a scalar (``c`` static or traced).
+
+    The tile is stored to the VMEM ``stage_ref`` and copied to the SMEM
+    ``smem_ref`` ``SMEM_ROWS`` rows at a time (``scalar_scratch``); a
+    ``fori_loop`` walks each chunk's rows.  Callers walk a row's columns
+    with a rolled ``fori_loop`` too, so a kernel's trace (and its
+    interpret-mode compile) does not grow with the tile."""
+    rows, w = vals.shape
+    ch = smem_ref.shape[0]
+    stage_ref[:, :w] = vals.astype(jnp.int32)
+    for k0 in range(0, rows, ch):
+        n = min(ch, rows - k0)
+        pltpu.sync_copy(stage_ref.at[pl.ds(k0, n)], smem_ref.at[pl.ds(0, n)])
+
+        def body(i, carry, k0=k0):
+            row_fn(k0 + i, lambda c: smem_ref[i, c])
+            return carry
+
+        jax.lax.fori_loop(0, n, body, 0)
+
+
+def _lane_iota(width: int = LANE):
+    return jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+
+
+def lane_gather(tbl_ref, idx):
+    """``out[r, c] = flat(tbl_ref)[idx[r, c]]`` for a row-layout table ref.
+
+    ``idx``: int (rows, w), every entry in ``[0, capacity)``.  Each entry
+    loads its table row with a dynamic sublane index and selects its lane
+    with a one-hot reduce (exactly one non-zero term, so the sum is the
+    entry bit for bit)."""
+    rows, w = idx.shape
+    wp = lane_pad(w)
+    dtype = tbl_ref.dtype
+    lane, col_ids = _lane_iota(), _lane_iota(wp)
+
+    def scoped(got_ref, stage_ref, smem_ref):
+        def row_fn(r, at):
+            def col(c, out):
+                v = at(c)
+                row = tbl_ref[pl.ds(v >> LANE_BITS, 1), :]
+                hit = jnp.sum(jnp.where(lane == (v & (LANE - 1)), row, 0),
+                              axis=1, keepdims=True).astype(dtype)
+                return jnp.where(col_ids == c, hit, out)
+
+            got_ref[pl.ds(r, 1), :] = jax.lax.fori_loop(
+                0, w, col, jnp.zeros((1, wp), dtype))
+
+        for_each_row(idx, row_fn, stage_ref, smem_ref)
+        return got_ref[...][:, :w]
+
+    return pl.run_scoped(scoped, pltpu.VMEM((rows, wp), dtype),
+                         *scalar_scratch(rows, w))
+
+
+def update_entry(ref, v, fn) -> None:
+    """``flat(ref)[v] = fn(flat(ref)[v])`` for a row-layout ref (one
+    dynamic-sublane row load, a lane-masked select, one row store)."""
+    row_idx = pl.ds(v >> LANE_BITS, 1)
+    row = ref[row_idx, :]
+    ref[row_idx, :] = jnp.where(_lane_iota() == (v & (LANE - 1)),
+                                fn(row), row)
+
+
+def gather_scratch_bytes(block_rows: int, width: int) -> int:
+    """VMEM bytes one in-kernel ``lane_gather`` holds on top of the tiles
+    the working set already counts: the int32 staging tile of
+    ``for_each_row`` and the gathered tile, both lane-padded (the SMEM
+    chunk lives outside VMEM)."""
+    return 2 * block_rows * lane_pad(width) * 4
 
 
 def onehot_lanes(x, depth: int):

@@ -2,8 +2,8 @@
 
 ``interpret=None`` on every wrapper resolves through
 ``kernels.backend.default_interpret`` — compiled mode (interpret=False)
-whenever the default JAX backend has a compiled Pallas target (TPU/Mosaic,
-GPU/Triton), interpret mode otherwise.  The kernel modules apply the same
+whenever the default JAX backend is a TPU (Mosaic), interpret mode
+otherwise.  The kernel modules apply the same
 default themselves; the wrappers resolve eagerly only so the jit static
 argnames see a concrete bool.
 """
@@ -37,36 +37,37 @@ def fused_stage(chain_fn, *, in_dtype, out_dtype, hex_width=0,
 
 
 def output_dataflow(inputs, tables, steps, terminals, out_dtype, *,
-                    pad_cols_to=1, block_rows=256, interpret=None):
+                    pad_cols_to=1, block_rows=256, interpret=None,
+                    vmem_limit_bytes=None):
     """One PackOutput's full streaming program as a single Pallas kernel."""
     if interpret is None:
         interpret = default_interpret()
     return jax.jit(_dataflow.make_output_dataflow(
         inputs, tables, steps, terminals, out_dtype,
-        pad_cols_to=pad_cols_to, block_rows=block_rows, interpret=interpret))
+        pad_cols_to=pad_cols_to, block_rows=block_rows, interpret=interpret,
+        vmem_limit_bytes=vmem_limit_bytes))
 
 
 def group_dataflow(inputs, tables, steps, outputs, *,
-                   block_rows=256, interpret=None):
+                   block_rows=256, interpret=None, vmem_limit_bytes=None):
     """A DataflowGroup's merged streaming program — several PackOutputs'
     packed blocks from a single Pallas kernel."""
     if interpret is None:
         interpret = default_interpret()
     return jax.jit(_dataflow.make_group_dataflow(
-        inputs, tables, steps, outputs,
-        block_rows=block_rows, interpret=interpret))
+        inputs, tables, steps, outputs, block_rows=block_rows,
+        interpret=interpret, vmem_limit_bytes=vmem_limit_bytes))
 
 
 def fit_dataflow(inputs, steps, value_buf, capacity, *,
-                 partitions=1, block_rows=256, interpret=None):
+                 block_rows=256, interpret=None, vmem_limit_bytes=None):
     """One VocabFit's full fit chunk (decode + bound + first-pos/count
-    build) as a single Pallas kernel.  ``partitions`` splits the accumulator
-    table across the grid (the vocab-build HBM-bank pattern)."""
+    build) as a single Pallas kernel."""
     if interpret is None:
         interpret = default_interpret()
     return jax.jit(_dataflow.make_fit_dataflow(
-        inputs, steps, value_buf, capacity, partitions=partitions,
-        block_rows=block_rows, interpret=interpret))
+        inputs, steps, value_buf, capacity, block_rows=block_rows,
+        interpret=interpret, vmem_limit_bytes=vmem_limit_bytes))
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "partitions", "interpret"))

@@ -21,18 +21,20 @@ def fused_chain(x, chain_fn):
 def hex2int_digit_major(x):
     """uint8[w, ...] ASCII-hex digit planes -> int32[...] (two's complement).
 
-    All-zero strings (missing) map to operators.INT_MISSING.
+    All-zero strings (missing) map to operators.INT_MISSING.  Digit planes
+    are folded one at a time in int32 (a left shift by 4 and an or are the
+    same bits in int32 and uint32), so no op reduces over the digit axis —
+    the form Mosaic lowers inside a kernel.
     """
-    w = x.shape[0]
-    missing = jnp.all(x == 0, axis=0)
-    c = jnp.where(x == 0, jnp.uint8(48), x).astype(jnp.int32)
-    dig = jnp.where(c >= 97, c - 87, jnp.where(c >= 65, c - 55, c - 48))
-    dig = dig.astype(jnp.uint32)
-    val = jnp.zeros(x.shape[1:], jnp.uint32)
-    for i in range(w):
-        val = (val << jnp.uint32(4)) | dig[i]
-    out = val.astype(jnp.int32)
-    return jnp.where(missing, jnp.int32(-(2 ** 31)), out)
+    missing = None
+    val = jnp.zeros(x.shape[1:], jnp.int32)
+    for i in range(x.shape[0]):
+        c = x[i].astype(jnp.int32)
+        missing = (c == 0) if missing is None else missing & (c == 0)
+        c = jnp.where(c == 0, 48, c)
+        dig = jnp.where(c >= 97, c - 87, jnp.where(c >= 65, c - 55, c - 48))
+        val = (val << 4) | dig
+    return jnp.where(missing, jnp.int32(-(2 ** 31)), val)
 
 
 # ---------------------------------------------------------------------------
@@ -42,9 +44,10 @@ def hex2int_digit_major(x):
 def vocab_build_chunk(values, capacity):
     """First-occurrence position of each value within one chunk.
 
-    values: int32[n] in [0, capacity). Returns int32[capacity], with
-    2**31 - 1 marking "absent in this chunk".
+    values: int32[n] (or any shape, read row-major) in [0, capacity).
+    Returns int32[capacity], with 2**31 - 1 marking "absent in this chunk".
     """
+    values = values.reshape(-1)
     n = values.shape[0]
     init = jnp.full((capacity,), jnp.int32(2 ** 31 - 1))
     pos = jnp.arange(n, dtype=jnp.int32)

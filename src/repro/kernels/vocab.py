@@ -14,14 +14,13 @@ VocabMap — keyed lookups against the frozen table.  Partition-parallel form:
 each grid step gathers hits for its table partition; a max-combine across
 partitions assembles the result (every key hits exactly one partition, misses
 contribute -1).  This avoids unsupported full-table dynamic gathers when the
-table exceeds VMEM; the in-partition gather is the banked lane gather of
-``kernels.lanes`` (no flat reshapes — the form Mosaic lowers).
+table exceeds VMEM; the in-partition gather is ``kernels.lanes.lane_gather``.
 
-Partition blocks are lane-padded: each partition of ``capacity``
-occupies ``lane_pad(capacity // partitions)`` lanes of the kernel-side
-buffer (padding lanes are inert — bounds checks use the logical partition
-size) and the wrappers re-interleave the logical table on return, so any
-``capacity % 128`` works in compiled mode.
+Each partition travels in the row layout of ``kernels.lanes``
+(``(table_rows(capacity // partitions), 128)``, rows stacked per partition);
+the build reads the stream's values as scalars and updates one entry per
+value with a row read-modify-write.  The wrappers re-interleave the logical
+table on return, so any ``capacity % 128`` works in compiled mode.
 
 ``interpret=None`` resolves through ``kernels.backend.default_interpret``.
 """
@@ -45,19 +44,27 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _unpad_partitions(t, partitions: int, part: int, part_pad: int):
-    """(1, partitions*part_pad) kernel buffer -> logical [capacity] table."""
-    t = t.reshape(partitions, part_pad)[:, :part].reshape(-1)
-    return t
+def _partition_rows(flat, partitions: int, part: int):
+    """Logical [partitions * part] table -> per-partition row layouts
+    stacked along rows: (partitions * table_rows(part), 128)."""
+    return jnp.concatenate(
+        [lanes.to_rows(flat[i * part:(i + 1) * part])
+         for i in range(partitions)], axis=0)
+
+
+def _unpartition_rows(t, partitions: int, part: int):
+    """Inverse of ``_partition_rows``: -> logical [partitions * part]."""
+    return t.reshape(partitions, -1)[:, :part].reshape(-1)
 
 
 # ---------------------------------------------------------------------------
 # VocabGen: chunk-local first-occurrence build
 # ---------------------------------------------------------------------------
 
-def _build_kernel(vals_ref, fp_ref, *, part_size: int, n_vals: int):
-    """Grid dim 0 = table partition p. fp_ref block: partition of first_pos
-    (lane-padded; only the first ``part_size`` lanes are logical)."""
+def _build_kernel(vals_ref, fp_ref, *, part_size: int, width: int,
+                  n_rows: int):
+    """Grid dim 0 = table partition p, dim 1 = row tile of the stream.
+    fp_ref: partition p of first_pos in the row layout."""
     p = pl.program_id(0)
     lo = p * part_size
 
@@ -65,61 +72,73 @@ def _build_kernel(vals_ref, fp_ref, *, part_size: int, n_vals: int):
     def _init():
         fp_ref[...] = jnp.full(fp_ref.shape, ABSENT32, fp_ref.dtype)
 
-    vals = vals_ref[...]  # (1, chunk) int32 block of the stream
-    chunk = vals.shape[-1]
-    base = pl.program_id(1) * chunk
+    vals = vals_ref[...][:, :width]
+    br = vals.shape[0]
+    row0 = pl.program_id(1) * br
 
-    def body(i, _):
-        v = vals[0, i] - lo
-        inb = (v >= 0) & (v < part_size)
+    def row_fn(r, at):
+        gr = row0 + r
 
-        @pl.when(inb & (base + i < n_vals))
-        def _upd():
-            cur = fp_ref[0, v]
-            fp_ref[0, v] = jnp.minimum(cur, base + i)
+        def col(c, carry):
+            v = at(c)
+            local = v - lo
+            ok = (gr < n_rows) & (v >= 0) & (local >= 0) & (local < part_size)
+            pos = jnp.where(ok, gr * width + c, ABSENT32)
+            lanes.update_entry(fp_ref, jnp.where(ok, local, 0),
+                               lambda row: jnp.minimum(row, pos))
+            return carry
 
-        return 0
+        jax.lax.fori_loop(0, width, col, 0)
 
-    jax.lax.fori_loop(0, chunk, body, 0)
+    pl.run_scoped(
+        lambda stage_ref, smem_ref: lanes.for_each_row(
+            vals, row_fn, stage_ref, smem_ref),
+        *lanes.scalar_scratch(br, width))
 
 
 def vocab_build_chunk(values, capacity: int, *, partitions: int = 1,
-                      stream_block: int = 4096,
+                      block_rows: int = 256,
                       interpret: Optional[bool] = None):
     """First-occurrence position within one chunk. int32[capacity], ABSENT32=absent.
 
-    values: int32[n] in [0, capacity).
+    values: int32[n] or int32[rows, w] (positions are row-major flat
+    offsets either way); entries outside [0, capacity) drop.
     """
     if interpret is None:
         interpret = default_interpret()
-    n = int(values.shape[0])
     if capacity % max(partitions, 1):
         raise ValueError("capacity must divide evenly into partitions")
+    vals = values.reshape(values.shape[0], -1)
+    rows, width = vals.shape
     part = capacity // partitions
-    part_pad = lanes.lane_pad(part)
-    nb = _round_up(max(n, 1), stream_block)
-    vp = jnp.pad(values, (0, nb - n), constant_values=-1).reshape(1, nb)
+    part_rows = lanes.table_rows(part)
+    br = min(block_rows, _round_up(max(rows, 1), 8))
+    rp = _round_up(max(rows, 1), br)
+    wp = lanes.lane_pad(width)
+    vp = jnp.pad(vals, ((0, rp - rows), (0, wp - width)), constant_values=-1)
 
     out = pl.pallas_call(
-        functools.partial(_build_kernel, part_size=part, n_vals=n),
-        grid=(partitions, nb // stream_block),
-        in_specs=[pl.BlockSpec((1, stream_block), lambda p, c: (0, c))],
-        out_specs=pl.BlockSpec((1, part_pad), lambda p, c: (0, p)),
-        out_shape=jax.ShapeDtypeStruct((1, partitions * part_pad), jnp.int32),
+        functools.partial(_build_kernel, part_size=part, width=width,
+                          n_rows=rows),
+        grid=(partitions, rp // br),
+        in_specs=[pl.BlockSpec((br, wp), lambda p, r: (r, 0))],
+        out_specs=pl.BlockSpec((part_rows, lanes.LANE), lambda p, r: (p, 0)),
+        out_shape=jax.ShapeDtypeStruct((partitions * part_rows, lanes.LANE),
+                                       jnp.int32),
         interpret=interpret,
     )(vp)
-    return _unpad_partitions(out, partitions, part, part_pad)
+    return _unpartition_rows(out, partitions, part)
 
 
 # ---------------------------------------------------------------------------
 # VocabMap: partition-parallel gather
 # ---------------------------------------------------------------------------
 
-def _lookup_kernel(x_ref, tbl_ref, o_ref, *, part_size: int):
+def _lookup_kernel(x_ref, tbl_ref, o_ref, *, part_size: int, cols: int):
     """Grid: (row blocks, partitions). o accumulates max over partitions."""
     p = pl.program_id(1)
     lo = p * part_size
-    x = x_ref[...]
+    x = x_ref[...][:, :cols]
 
     @pl.when(p == 0)
     def _init():
@@ -127,11 +146,9 @@ def _lookup_kernel(x_ref, tbl_ref, o_ref, *, part_size: int):
 
     local = x - lo
     inb = (local >= 0) & (local < part_size)
-    safe = jnp.where(inb, local, 0)
-    tbl = tbl_ref[...]  # (1, lane_pad(part_size))
-    got = lanes.lane_gather(tbl, safe)
+    got = lanes.lane_gather(tbl_ref, jnp.where(inb, local, 0))
     got = jnp.where(inb, got, -1)
-    o_ref[...] = jnp.maximum(o_ref[...], got)
+    o_ref[:, :cols] = jnp.maximum(o_ref[:, :cols], got)
 
 
 def vocab_lookup(x, table, n_unique, *, partitions: int = 1,
@@ -147,24 +164,22 @@ def vocab_lookup(x, table, n_unique, *, partitions: int = 1,
     if capacity % max(partitions, 1):
         raise ValueError("capacity must divide evenly into partitions")
     part = capacity // partitions
-    part_pad = lanes.lane_pad(part)
+    part_rows = lanes.table_rows(part)
     br = min(block_rows, _round_up(rows, 8))
-    bc = _round_up(cols, 128)
+    bc = lanes.lane_pad(cols)
     rp = _round_up(rows, br)
     xp = jnp.pad(x, ((0, rp - rows), (0, bc - cols)))
-    tbl = jnp.pad(table.reshape(partitions, part),
-                  ((0, 0), (0, part_pad - part))).reshape(1, -1)
 
     out = pl.pallas_call(
-        functools.partial(_lookup_kernel, part_size=part),
+        functools.partial(_lookup_kernel, part_size=part, cols=cols),
         grid=(rp // br, partitions),
         in_specs=[
             pl.BlockSpec((br, bc), lambda r, p: (r, 0)),
-            pl.BlockSpec((1, part_pad), lambda r, p: (0, p)),
+            pl.BlockSpec((part_rows, lanes.LANE), lambda r, p: (p, 0)),
         ],
         out_specs=pl.BlockSpec((br, bc), lambda r, p: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, bc), jnp.int32),
         interpret=interpret,
-    )(xp, tbl)
+    )(xp, _partition_rows(table, partitions, part))
     out = out[:rows, :cols]
     return jnp.where(out >= 0, out, n_unique).astype(jnp.int32)

@@ -84,7 +84,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
             mem = compiled.memory_analysis()
             rec["memory"] = _mem_dict(mem)
-            xla_cost = hlo_cost.xla_cost_analysis(compiled)
+            xla_cost = compiled.cost_analysis()
             rec["xla_cost_analysis"] = {
                 k: float(v) for k, v in xla_cost.items()
                 if isinstance(v, (int, float)) and

@@ -7,6 +7,14 @@ touches jax device state — the dry-run must set XLA_FLAGS before first init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: GSPMD propagates shardings and
+    ``shard_hint``'s ``with_sharding_constraint`` may name any axis
+    (``jax.make_mesh`` defaults to ``Explicit`` axes, which refuse it)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,11 +23,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     DCN/ICI-superpod data-parallel dimension."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
     """Small mesh over whatever devices exist (tests / local runs)."""
     n = len(jax.devices())
     model_axis = min(model_axis, n)
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return _auto_mesh((n // model_axis, model_axis), ("data", "model"))

@@ -28,6 +28,7 @@ import jax
 from repro.configs.base import TrainConfig
 from repro.core.pipeline import paper_pipeline
 from repro.data.source import Source
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import dlrm
 from repro.online import (BusServer, EventBus, OnlineConfig, OnlineTrainer,
                           replay)
@@ -162,6 +163,7 @@ def build_service(args):
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     trainer, bus, producer = build_service(args)
     t = threading.Thread(target=producer, name="online-producer")
     t.start()

@@ -25,6 +25,7 @@ from repro.configs.registry import get_config, get_reduced
 from repro.core.pipeline import lm_token_pipeline
 from repro.data.source import Source
 from repro.distributed import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.presets import train_preset
 from repro.models.api import build_model, input_specs
@@ -99,6 +100,7 @@ def main(argv=None):
                          "executor knobs (credits, prefetch depth, "
                          "lookahead window; row tile/fuse on pallas)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     tcfg = train_preset(args.arch)

@@ -56,15 +56,15 @@ def jit_train_step(train_step, mesh, state_shapes, batch_shapes, *,
                    donate_batch: bool = False):
     """pjit the step with explicit in/out shardings and state donation.
 
-    ``donate_batch=True`` additionally donates the batch argument — the
-    zero-copy half of the ETL handoff: the streaming executor's place stage
-    already delivers buffers in the exact ``in_shardings`` layout, so with
-    donation XLA reuses the packed batch's HBM for step temporaries instead
-    of copying (the paper's "FPGA writes training-ready batches directly
-    into accelerator memory").  Only enable it when every batch is consumed
-    exactly once (always true for executor-fed loops); a donated batch is
-    invalid after the step.  The CPU backend cannot alias donated inputs,
-    so the request is ignored there (no warning spam on smoke runs).
+    ``donate_batch=True`` additionally donates the batch argument, which
+    the streaming executor's place stage already delivers in the exact
+    ``in_shardings`` layout.  XLA can only reuse a donated buffer for an
+    output of the same shape; a DLRM step has none, and XLA:TPU warns that
+    the donated batch is unusable.  Only enable it when every batch is
+    consumed exactly once (always true for executor-fed loops); a donated
+    batch is invalid after the step.  The CPU backend cannot alias donated
+    inputs, so the request is ignored there (no warning spam on smoke
+    runs).
 
     NOTE: for grad-accumulation sharding, build the step via
     ``make_train_step(loss, tcfg, grad_specs=param_specs(...))``.

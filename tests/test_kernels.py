@@ -56,7 +56,7 @@ def test_fit_dataflow_matches_staged_build(rows, width, cap, partitions):
     """Fused fit kernel == staged build kernel + counts oracle, including
     out-of-range values: negatives and >= capacity drop on both paths
     (regression: JAX scatter index normalization must not wrap -1 to the
-    last table slot).  Partitioned accumulators agree with partitions=1."""
+    last table slot).  The staged build agrees at every partition count."""
     from repro.kernels.dataflow import StreamInput, make_fit_dataflow
 
     vals = RNG.integers(0, cap, size=(rows, width)).astype(np.int32)
@@ -64,8 +64,7 @@ def test_fit_dataflow_matches_staged_build(rows, width, cap, partitions):
     if vals.size > 3:
         vals.reshape(-1)[1] = cap + 5                      # overflow id
     fn = make_fit_dataflow([StreamInput("v", width, np.dtype(np.int32))],
-                           [], "v", cap, partitions=partitions,
-                           interpret=True)
+                           [], "v", cap, interpret=True)
     got_fp, got_cnt = (np.asarray(a) for a in fn(jnp.asarray(vals)))
     flat = vals.reshape(-1)
     want_fp = np.full(cap, 2 ** 31 - 1, np.int32)
@@ -78,7 +77,8 @@ def test_fit_dataflow_matches_staged_build(rows, width, cap, partitions):
     np.testing.assert_array_equal(got_cnt, want_cnt)
     # the staged Pallas build drops out-of-range values too: bit-equal
     staged = np.asarray(ops.vocab_build_chunk(
-        jnp.asarray(flat), capacity=cap, partitions=1, interpret=True))
+        jnp.asarray(vals), capacity=cap, partitions=partitions,
+        interpret=True))
     np.testing.assert_array_equal(got_fp, staged)
 
 

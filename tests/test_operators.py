@@ -35,6 +35,23 @@ def test_logarithm():
     check_op(O.Logarithm(), x)
 
 
+def test_log1p_f32_within_one_ulp():
+    """log1p_f32 is within 1 ulp of float64 log1p rounded to float32 from
+    1e-30 to the float32 maximum and on (-1, 0), and keeps log1p's special
+    values."""
+    x = np.concatenate([
+        np.linspace(0, 10, 50001), np.geomspace(1e-30, 3.4e38, 50001),
+        -np.geomspace(1e-9, 0.999, 5001),
+        RNG.normal(size=20000) ** 2 * 3]).astype(np.float32)
+    want = np.log1p(x.astype(np.float64)).astype(np.float32)
+    got = np.asarray(O.log1p_f32(jnp.asarray(x)))
+    ulps = np.abs(got.astype(np.float64) - want) / np.spacing(np.abs(want))
+    assert ulps.max() <= 1.0, (ulps.max(), x[ulps.argmax()])
+    special = np.array([-1.0, -2.0, np.inf, np.nan, 0.0], np.float32)
+    np.testing.assert_array_equal(np.asarray(O.log1p_f32(jnp.asarray(special))),
+                                  [-np.inf, np.nan, np.inf, np.nan, 0.0])
+
+
 def test_fill_missing_float():
     x = np.array([3.2, np.nan, -1.0], np.float32)
     out = O.FillMissing(0.0).numpy(x)

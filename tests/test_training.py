@@ -94,7 +94,7 @@ def test_compressed_psum_error_feedback_converges():
         pytest.skip("no devices")
     # single-device shard_map still exercises the code path
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     mesh = Mesh(np.array(devs[:1]), ("d",))
     g = {"w": jnp.asarray(np.random.default_rng(1).normal(size=(32,)) * 0.1,
                           jnp.float32)}
@@ -256,3 +256,42 @@ def test_prune_uncommitted_garbage_cannot_displace_committed():
         restored = ck.restore(d, _tiny_state(0.0))
         np.testing.assert_array_equal(np.asarray(restored["w"]),
                                       _tiny_state(7)["w"])
+
+
+def test_compile_cache_fixed_dir_unless_env_set(monkeypatch, tmp_path):
+    """Without JAX_COMPILATION_CACHE_DIR the cache goes to one fixed
+    directory of the checkout; with it, nothing is set (JAX reads it)."""
+    from repro.launch import compile_cache as cc
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert str(cc.CACHE_DIR) == os.path.join(checkout, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cc.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert cc.enable_compile_cache() == str(cc.CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(cc.CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_lands_in_env_dir(tmp_path):
+    """A fresh process honours JAX_COMPILATION_CACHE_DIR: the compiled
+    program is written there."""
+    import subprocess
+    import sys
+    code = (
+        "import jax\n"
+        "from repro.launch.compile_cache import enable_compile_cache\n"
+        "enable_compile_cache()\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "jax.jit(lambda x: x * 2 + 1)(jax.numpy.arange(8)).block_until_ready()\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..", "src"))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert os.listdir(tmp_path / "cache")
