@@ -10,6 +10,7 @@ Emits:
   fig14/cpu_fed_blocking, fig14/cpu_fed_overlapped  (numpy host ETL — the
       paper's headline regime: slow CPU ETL hidden behind the train step)
   fig8/<stage>                                per-stage breakdown
+  fig8/consumer_wait                          time the trainer starved
   fig14/utilization_gain                      overlapped - blocking (pp)
 
 ``--steps N`` overrides the batch count (CI smoke: ``--steps 3`` exercises
@@ -18,13 +19,13 @@ needs enough batches to amortize warmup).
 
 ``--sweep`` runs the Fig-8 sensitivity grid instead: credits x
 stage-cost-ratio cells with pinned (sleep-based) stage costs, emitting
-trainer utilization per cell —
+the time the trainer starved per cell —
 
   fig8_sweep/credits=C_ratio=R
 
-The deterministic costs isolate the staging-depth effect: utilization
-should rise with credits while ETL is the bottleneck (ratio > 1) and
-saturate near 100% once ETL hides (ratio <= 1, credits >= 2).
+The deterministic costs isolate the staging-depth effect: starvation
+should fall with credits while ETL is the bottleneck (ratio > 1) and
+vanish once ETL hides (ratio <= 1, credits >= 2).
 ``--sweep-credits`` / ``--sweep-ratios`` override the grid (the nightly CI
 smoke runs a single cell).
 
@@ -114,16 +115,12 @@ def run_overlapped(job, step, state):
 
 
 def _sweep_cell(job, steps, train_s):
-    """Run one pinned-cost cell; return (wall_s, util, stats)."""
+    """Run one pinned-cost cell; return (wall_s, stats)."""
     t0 = time.perf_counter()
-    train_total = 0.0
     with job.batches() as ex:
         for _ in ex:
-            ts = time.perf_counter()
             time.sleep(train_s)
-            train_total += time.perf_counter() - ts
-    wall = time.perf_counter() - t0
-    return wall, job.stats().trainer_utilization(train_total), job.stats()
+    return time.perf_counter() - t0, job.stats()
 
 
 def run_sweep(credits_list, ratios, steps, *, autotune=False):
@@ -132,7 +129,7 @@ def run_sweep(credits_list, ratios, steps, *, autotune=False):
     Stage costs are pinned sleeps (deterministic, hardware-independent):
     the transform stage costs ``ratio`` x the train step.  Each cell runs
     the real staged executor through the ``EtlJob`` facade and reports the
-    trainer's utilization = train_time / (train_time + starvation).
+    time the trainer starved (``consumer_wait_s``).
 
     With ``autotune``, one extra cell per ratio starts at credits=1 and
     lets the PipelineController choose the staging depth from measured
@@ -155,26 +152,26 @@ def run_sweep(credits_list, ratios, steps, *, autotune=False):
     for credits in credits_list:
         for ratio in ratios:
             job = make_job(credits, ratio, name=f"sweep-c{credits}-r{ratio}")
-            wall, util, stats = _sweep_cell(job, steps, train_s)
+            wall, stats = _sweep_cell(job, steps, train_s)
             emit(f"fig8_sweep/credits={credits}_ratio={ratio:g}", wall,
-                 f"util={util:.2%}|starved={stats.consumer_wait_s:.3f}s")
+                 f"starved={stats.consumer_wait_s:.3f}s")
             records.append(dict(mode="sweep", credits=credits, ratio=ratio,
-                                steps=steps, wall_s=wall, util=util,
+                                steps=steps, wall_s=wall,
                                 starved_s=stats.consumer_wait_s))
     if autotune:
         for ratio in ratios:
             job = make_job(1, ratio, autotune=True,
                            max_credits=max(credits_list),
                            name=f"sweep-autotuned-r{ratio}")
-            wall, util, stats = _sweep_cell(job, steps, train_s)
+            wall, stats = _sweep_cell(job, steps, train_s)
             ctl = stats.controller
             chosen = ctl.knob_values() if ctl is not None else {}
             decisions = ctl.decision_counts() if ctl is not None else {}
             emit(f"fig8_sweep/autotuned_ratio={ratio:g}", wall,
-                 f"util={util:.2%}|chosen="
+                 f"starved={stats.consumer_wait_s:.3f}s|chosen="
                  + ",".join(f"{k}={v}" for k, v in sorted(chosen.items())))
             records.append(dict(mode="autotuned", ratio=ratio, steps=steps,
-                                wall_s=wall, util=util,
+                                wall_s=wall,
                                 starved_s=stats.consumer_wait_s,
                                 chosen=chosen, decisions=decisions))
     return records
@@ -259,8 +256,8 @@ def main(argv=None):
         emit(f"fig8/{name}", s["busy_s"],
              f"items={s['items']}|wait_in={s['wait_in_s']:.3f}s"
              f"|wait_out={s['wait_out_s']:.3f}s|occ={s['occupancy']:.1%}")
-    emit("fig8/overlapped_etl", stats.overlapped_etl_s,
-         f"etl_hidden_behind_training={stats.overlapped_etl_s:.3f}s")
+    emit("fig8/consumer_wait", stats.consumer_wait_s,
+         f"trainer_starved={stats.consumer_wait_s:.3f}s")
 
     gain_pp = (cpu_ov - cpu_block) * 100
     emit("fig14/utilization_gain", cpu_ov_total,

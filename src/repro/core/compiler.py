@@ -518,6 +518,10 @@ class CompiledPipeline:
                     interpret=self.interpret)
 
         def apply_fn(tables, n_uniques, resolved, cols):
+            with jax.named_scope("etl_apply"):
+                return apply_body(tables, n_uniques, resolved, cols)
+
+        def apply_body(tables, n_uniques, resolved, cols):
             bufs = dict(self._assemble_sources_jnp(cols))
             for s in plan.stages:
                 if s.stage_id not in staged_ids:
@@ -605,6 +609,10 @@ class CompiledPipeline:
         fit_bufs = self._fit_bufs
 
         def fit_chunk(cols):
+            with jax.named_scope("etl_fit"):
+                return fit_body(cols)
+
+        def fit_body(cols):
             bufs = dict(self._assemble_sources_jnp(cols, fit_bufs))
             for s in plan.stages:
                 if s.stage_id not in staged_ids:
@@ -713,9 +721,10 @@ class CompiledPipeline:
                          for vf in self.plan.vocab_fits}
             fit_bufs = self._fit_bufs
             for ci, raw in enumerate(batch_iter):
-                sources = {k: jnp.asarray(v)
-                           for k, v in self._raw_columns(raw, fit_bufs).items()}
-                chunk_fps = self._fit_chunk_jit(sources)
+                with jax.profiler.TraceAnnotation("etl.fit", chunk=ci):
+                    sources = {k: jnp.asarray(v) for k, v
+                               in self._raw_columns(raw, fit_bufs).items()}
+                    chunk_fps = self._fit_chunk_jit(sources)
                 for vid, (fp, cnt) in chunk_fps.items():
                     states[vid] = kref.vocab_merge(states[vid], fp, ci,
                                                    chunk_counts=cnt)

@@ -54,6 +54,7 @@ from dataclasses import replace
 from typing import Callable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # Keys the lookahead stage adds to each released payload (host numpy arrays).
 PLAN_KEYS = ("emb_slot", "emb_cold", "emb_stage_rows",
@@ -389,17 +390,18 @@ class LookaheadStage(threading.Thread):
                 return
             t1 = mono()
             try:
-                idx = self._indices(item.payload)
-                if self.planner is None:
-                    self.planner = LookaheadPlanner(
-                        self.cfg, idx.shape[1], stats=self.cache_stats)
-                self.planner.push(idx)
-                self._buf.append(item)
-                ok = True
-                # drain to the live window target (shrunk knobs release the
-                # excess; at steady state this pops exactly one per push)
-                while ok and len(self._buf) >= self._window:
-                    ok = self._release()
+                with TraceAnnotation("etl.lookahead", batch=item.batch):
+                    idx = self._indices(item.payload)
+                    if self.planner is None:
+                        self.planner = LookaheadPlanner(
+                            self.cfg, idx.shape[1], stats=self.cache_stats)
+                    self.planner.push(idx)
+                    self._buf.append(item)
+                    ok = True
+                    # drain to the live window target (shrunk knobs release
+                    # the excess; at steady state this pops one per push)
+                    while ok and len(self._buf) >= self._window:
+                        ok = self._release()
             except Exception as e:
                 if self.on_error:
                     self.on_error(e)

@@ -71,7 +71,8 @@ def stats_to_prometheus(stats: RuntimeStats, *, prefix: str = "repro_etl",
                     "stage_busy_seconds_total": lambda s: s.busy_s,
                     "stage_wait_in_seconds_total": lambda s: s.wait_in_s,
                     "stage_wait_out_seconds_total": lambda s: s.wait_out_s,
-                    "stage_drop_oldest_total": lambda s: s.drop_oldest}
+                    "stage_drop_oldest_total": lambda s: s.drop_oldest,
+                    "stage_bytes_total": lambda s: s.bytes}
     for name in sorted(stage_series):
         metric = f"{prefix}_{name}"
         lines.append(f"# TYPE {metric} counter")

@@ -25,15 +25,20 @@ remap + admit/evict plan (see ``etl_runtime/lookahead.py``).
   pipeline (the 1000-node posture: this is per-host, and hosts are
   independent).  Most callers construct executors through
   ``repro.session.EtlJob`` rather than directly.
-- **transform** dispatches the jitted apply-program.  JAX async dispatch means
-  the stage enqueues *device futures* — no host materialization, no
-  ``block_until_ready`` — so real ETL compute overlaps the trainer's step.
-- **place** double-buffers the H2D/layout transfer: with a trainer
-  ``NamedSharding`` (see ``etl_runtime/transfer.py``) batches are
-  ``device_put`` with the exact layout ``train_step`` declares in
-  ``in_shardings``, so delivered batches are donation-ready and H2D overlaps
-  device compute.  The ready queue holds ``credits`` batches — one being
-  consumed, the rest in flight (double buffering at credits=2).
+- **transform** hands one raw batch to the jitted apply-program: the raw
+  columns are copied to the device (``StageStats.bytes`` counts them) and
+  the program is dispatched.  JAX async dispatch means the stage enqueues
+  *device futures* — no host materialization, no ``block_until_ready`` —
+  so the apply's device time is not in the stage's ``busy_s``; the host's
+  hand-off, argument transfer included, is.  How much of that transfer
+  the host pays per batch is the benchmark's ``h2d_ms_per_batch``, read
+  from the runtime's host-to-device events inside the ``etl.transform``
+  span.
+- **place**: with a trainer ``NamedSharding`` (see
+  ``etl_runtime/transfer.py``) batches are ``device_put`` with the exact
+  layout ``train_step`` declares in ``in_shardings``, so delivered batches
+  are donation-ready.  The ready queue holds ``credits`` batches — one
+  being consumed, the rest in flight (double buffering at credits=2).
 - **deliver** is the consumer side (``__iter__`` / ``get_batch``); it records
   trainer starvation time.
 
@@ -67,6 +72,15 @@ Every stage records busy / wait-in / wait-out time (``stats.stages``), giving
 the paper's Fig-8-style per-stage breakdown consumed by
 ``benchmarks/bench_overlap.py``.
 
+Tracing: each stage's own work per batch is a ``jax.profiler`` span over
+the interval its ``busy_s`` counts — ``etl.read``, ``etl.transform``
+(with ``bytes``), ``etl.order``, ``etl.place``, ``etl.lookahead`` — and
+the consumer's wait is ``etl.deliver`` (with the ready queue's ``depth``),
+the interval ``consumer_wait_s`` counts.  Every span carries ``batch``,
+the read index riding the envelope, so one batch's spans join across
+threads.  The spans land in a profile only while one is being recorded;
+otherwise each costs about a microsecond.
+
 The read stage is also available standalone as ``SourcePrefetcher`` —
 ``EtlJob.fit`` uses it so the fit phase's (fused) chunk build overlaps
 source ingest exactly like apply overlaps training.
@@ -82,6 +96,7 @@ from dataclasses import dataclass, field, replace
 from typing import Callable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.semantics import PipelineSemantics
 from repro.data.source import Source
@@ -207,11 +222,13 @@ class CreditQueue:
 class _Envelope:
     """Per-batch sidecar riding every queue: the payload plus host-side
     metadata the stages consult without touching the (possibly device-
-    future) payload — the Source-provided ordering key and arrival time."""
+    future) payload — the Source-provided ordering key and arrival time,
+    and the read index that joins one batch's stage spans."""
 
     payload: object
     length_key: Optional[float] = None
     arrival: Optional[float] = None
+    batch: Optional[int] = None
 
 
 @dataclass
@@ -223,6 +240,7 @@ class StageStats:
     wait_in_s: float = 0.0    # blocked waiting for upstream input
     wait_out_s: float = 0.0   # blocked on downstream credits (backpressure)
     drop_oldest: int = 0      # batches this stage's put shed (freshness)
+    bytes: int = 0            # raw bytes handed to the device (transform)
 
     def occupancy(self) -> float:
         total = self.busy_s + self.wait_in_s + self.wait_out_s
@@ -231,7 +249,7 @@ class StageStats:
     def as_dict(self) -> dict:
         return {"items": self.items, "busy_s": self.busy_s,
                 "wait_in_s": self.wait_in_s, "wait_out_s": self.wait_out_s,
-                "drop_oldest": self.drop_oldest,
+                "drop_oldest": self.drop_oldest, "bytes": self.bytes,
                 "occupancy": self.occupancy()}
 
 
@@ -332,28 +350,10 @@ class RuntimeStats:
         ages = np.asarray(self.delivered_ages)
         return {f"p{p}": float(np.percentile(ages, p)) for p in (50, 95, 99)}
 
-    # -- compatibility views over the per-stage accounting ----------------
-
-    @property
-    def etl_time_s(self) -> float:
-        """Total ETL work time (transform dispatch + placement)."""
-        return sum(s.busy_s for n, s in self.stages.items()
-                   if n in ("transform", "place"))
-
     @property
     def producer_wait_s(self) -> float:
         """Time the producer side blocked on credits (ETL faster)."""
         return sum(s.wait_out_s for s in self.stages.values())
-
-    @property
-    def overlapped_etl_s(self) -> float:
-        """ETL work hidden behind training: busy time the trainer did not
-        pay for as starvation.  > 0 is the measured overlap win."""
-        return max(0.0, self.etl_time_s - self.consumer_wait_s)
-
-    def trainer_utilization(self, total_train_s: float) -> float:
-        denom = total_train_s + self.consumer_wait_s
-        return total_train_s / denom if denom > 0 else 1.0
 
     def stage_breakdown(self) -> dict:
         """Fig-8-style per-stage breakdown: {stage: {items, busy_s, ...}}."""
@@ -363,9 +363,12 @@ class RuntimeStats:
 class _Stage(threading.Thread):
     """One pipeline stage: pull → work → push, with full time accounting.
 
-    ``fn(item)`` returns the transformed item.  EOS is forwarded and the
-    stage exits; a stop event aborts promptly even mid-put (CreditQueue is
-    stop-aware, so a full downstream queue cannot deadlock teardown).
+    ``fn(item)`` returns the transformed item; each call is the profiler
+    span ``etl.<stage>`` with the envelope's ``batch``.  ``nbytes(payload)``
+    (the transform stage's) is added to ``stats.bytes`` and to the span's
+    ``bytes``.  EOS is forwarded and the stage exits; a stop event aborts
+    promptly even mid-put (CreditQueue is stop-aware, so a full downstream
+    queue cannot deadlock teardown).
     """
 
     def __init__(self, stats: StageStats, fn: Callable, in_q: CreditQueue,
@@ -374,10 +377,13 @@ class _Stage(threading.Thread):
                  on_in_timeout: Optional[Callable[[], None]] = None,
                  on_put: Optional[Callable[[int], None]] = None,
                  on_error: Optional[Callable[[BaseException], None]] = None,
+                 nbytes: Optional[Callable] = None,
                  clock: Optional[Clock] = None):
         super().__init__(name=f"etl-{stats.name}", daemon=True)
         self.stats = stats
         self.fn = fn
+        self.nbytes = nbytes
+        self.span = f"etl.{stats.name}"
         self.in_q = in_q
         self.out_q = out_q
         self.drop_oldest = drop_oldest
@@ -405,8 +411,13 @@ class _Stage(threading.Thread):
                 self.out_q.put(_EOS)
                 return
             t1 = mono()
+            args = {"batch": item.batch}
             try:
-                out = self.fn(item)
+                if self.nbytes is not None:
+                    args["bytes"] = self.nbytes(item.payload)
+                    self.stats.bytes += args["bytes"]
+                with TraceAnnotation(self.span, **args):
+                    out = self.fn(item)
             except Exception as e:
                 # never die silently: surface the error and stop the
                 # pipeline so the consumer unblocks instead of hanging
@@ -435,7 +446,10 @@ def _pump_source(source, out_q: CreditQueue, stats: StageStats,
     blocking put into a full queue).  ``wrap(raw, idx)`` transforms each
     item at read time (the executor stamps envelope metadata here);
     ``on_error`` sets the failure policy (the executor stops the whole
-    pipeline, the prefetcher records and re-raises at the consumer)."""
+    pipeline, the prefetcher records and re-raises at the consumer).  Each
+    read is the profiler span ``etl.read`` with the read index ``batch``;
+    the read that meets the stream's end is spanned and counted busy too,
+    and its span has no ``batch``."""
     mono = (clock or SYSTEM_CLOCK).monotonic
     try:
         it = iter(source)
@@ -443,15 +457,18 @@ def _pump_source(source, out_q: CreditQueue, stats: StageStats,
         while not stop.is_set():
             t0 = mono()
             try:
-                raw = next(it)
-                item = raw if wrap is None else wrap(raw, idx)
-            except StopIteration:
-                break
+                with TraceAnnotation("etl.read") as span:
+                    raw = next(it, _EOS)
+                    if raw is not _EOS:
+                        span.set_metadata(batch=idx)
+                        item = raw if wrap is None else wrap(raw, idx)
             except Exception as e:
                 if on_error is not None:
                     on_error(e)
                 return
             stats.busy_s += mono() - t0
+            if raw is _EOS:
+                break
             idx += 1
             t1 = mono()
             r = out_q.put(item)
@@ -461,6 +478,13 @@ def _pump_source(source, out_q: CreditQueue, stats: StageStats,
             stats.items += 1
     finally:
         out_q.put(_EOS)
+
+
+def _columns_nbytes(raw) -> int:
+    """Bytes of a raw batch's columns (a dict of arrays, or one array):
+    what the transform stage hands the device."""
+    cols = raw.values() if isinstance(raw, dict) else (raw,)
+    return int(sum(getattr(c, "nbytes", 0) for c in cols))
 
 
 def default_length_key(batch) -> float:
@@ -538,9 +562,10 @@ class _SortStage(threading.Thread):
                 return
             t1 = mono()
             try:
-                key = item.length_key
-                if key is None:
-                    key = self.length_key(item.payload)
+                with TraceAnnotation("etl.order", batch=item.batch):
+                    key = item.length_key
+                    if key is None:
+                        key = self.length_key(item.payload)
                 buf.append((key, item))
             except Exception as e:
                 if self.on_error:
@@ -803,7 +828,7 @@ class StreamingExecutor:
                    self._raw_q, self._packed_q,
                    in_timeout_s=self.read_timeout_s,
                    on_in_timeout=_on_straggler, on_error=_on_error,
-                   clock=ck),
+                   nbytes=_columns_nbytes, clock=ck),
             *self._stages,
             _Stage(self.stats.stages["place"], _env_fn(self.place),
                    place_in_q, place_out_q,
@@ -853,7 +878,7 @@ class StreamingExecutor:
             arrival = (self._arrival_fn(idx)
                        if self._arrival_fn is not None else None)
             self.stats.note_ingest(now=self.clock.monotonic())
-            return _Envelope(raw, key, arrival)
+            return _Envelope(raw, key, arrival, idx)
 
         _pump_source(self._source, self._raw_q, self.stats.stages["read"],
                      self._stop, wrap=wrap, on_error=self._on_error,
@@ -938,43 +963,43 @@ class StreamingExecutor:
         if self._error is not None:
             raise RuntimeError("ETL pipeline stage failed") from self._error
 
-    def __iter__(self):
-        self.start()
-        dst = self.stats.stages["deliver"]
-        mono = self.clock.monotonic
-        while True:
-            w0 = mono()
-            item = self._ready_q.get()
-            wait = mono() - w0
-            self.stats.consumer_wait_s += wait
-            dst.wait_in_s += wait
-            if item is _EOS or item is _STOPPED:
-                self._raise_if_failed()
-                return
-            self.stats.consumed += 1
-            dst.items += 1
-            if item.arrival is not None:
-                self.stats.note_delivered(item.arrival, now=mono())
-            self._adapt(wait)
-            yield item.payload
-
-    def get_batch(self, timeout: Optional[float] = None):
-        self.start()
+    def _deliver(self, timeout: Optional[float] = None):
+        """The deliver stage: the consumer's wait for the next ready
+        envelope, counted in ``consumer_wait_s`` and spanned as
+        ``etl.deliver`` (``depth``: the ready queue's length at the get,
+        ``batch``: the envelope's read index, absent at the stream's end).
+        None at the stream's end."""
         dst = self.stats.stages["deliver"]
         mono = self.clock.monotonic
         w0 = mono()
-        item = self._ready_q.get(timeout=timeout)
+        with TraceAnnotation("etl.deliver",
+                             depth=len(self._ready_q)) as span:
+            item = self._ready_q.get(timeout=timeout)
+            if isinstance(item, _Envelope):
+                span.set_metadata(batch=item.batch)
         wait = mono() - w0
         self.stats.consumer_wait_s += wait
         dst.wait_in_s += wait
         if item is _EOS or item is _STOPPED:
             self._raise_if_failed()
-            raise StopIteration
+            return None
         self.stats.consumed += 1
         dst.items += 1
         if item.arrival is not None:
             self.stats.note_delivered(item.arrival, now=mono())
         self._adapt(wait)
+        return item
+
+    def __iter__(self):
+        self.start()
+        while (item := self._deliver()) is not None:
+            yield item.payload
+
+    def get_batch(self, timeout: Optional[float] = None):
+        self.start()
+        item = self._deliver(timeout)
+        if item is None:
+            raise StopIteration
         return item.payload
 
     def stop(self):

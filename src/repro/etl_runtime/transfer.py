@@ -51,10 +51,3 @@ def put_packed(batch: dict, sharding: Optional[NamedSharding]) -> dict:
         out[k] = jax.device_put(v, NamedSharding(sharding.mesh, row_spec))
     return out
 
-
-def transfer_stats(batch: dict) -> dict:
-    """Bytes moved for the Fig-11 style transfer micro-benchmark."""
-    total = 0
-    for v in batch.values():
-        total += np.dtype(v.dtype).itemsize * int(np.prod(np.shape(v)))
-    return {"bytes": total}

@@ -125,6 +125,7 @@ def make_fused_stage(chain_fn, *, in_dtype, out_dtype, hex_width: int = 0,
         grid = (rp // br, cp // bc)
         out = pl.pallas_call(
             kernel,
+            name="etl_fused_stage",
             grid=grid,
             in_specs=[in_spec],
             out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
@@ -181,6 +182,7 @@ def make_packer(col_widths, in_dtypes, out_dtype, *, pad_cols_to: int = 128,
             for b, lw in zip(blocks, lane_widths)]
         out = pl.pallas_call(
             kernel,
+            name="etl_pack",
             grid=(rp // br,),
             in_specs=[pl.BlockSpec((br, lw), lambda r: (r, 0))
                       for lw in lane_widths],
@@ -333,6 +335,7 @@ def make_output_dataflow(inputs: Sequence[StreamInput],
         padded_tbls, tbl_specs = _table_layout(tables, tbls)
         out = pl.pallas_call(
             kernel,
+            name="etl_apply_output",
             grid=(rp // br,),
             in_specs=in_specs + tbl_specs,
             out_specs=pl.BlockSpec((br, lane_padded), lambda r: (r, 0)),
@@ -413,6 +416,7 @@ def make_group_dataflow(inputs: Sequence[StreamInput],
         padded_tbls, tbl_specs = _table_layout(tables, tbls)
         outs = pl.pallas_call(
             kernel,
+            name="etl_apply_group",
             grid=(rp // br,),
             in_specs=in_specs + tbl_specs,
             out_specs=[pl.BlockSpec((br, lp), lambda r: (r, 0))
@@ -515,6 +519,7 @@ def make_fit_dataflow(inputs: Sequence[StreamInput],
         acc = jax.ShapeDtypeStruct((acc_rows, lanes.LANE), jnp.int32)
         fp, cnt = pl.pallas_call(
             functools.partial(kernel, n_rows=rows),
+            name="etl_fit_build",
             grid=(rp // br,),
             in_specs=in_specs,
             out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 2,

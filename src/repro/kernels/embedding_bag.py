@@ -149,6 +149,7 @@ def embedding_bag(table, indices, *, partitions: int = 1, block_batch: int = 128
 
     rows = pl.pallas_call(
         functools.partial(_gather_kernel, part_rows=part, nnz=nnz),
+        name="embedding_bag",
         grid=(bp // bb, parts),
         in_specs=[
             pl.BlockSpec((bb, nnz_lane), lambda b, p: (b, 0)),
@@ -230,6 +231,7 @@ def embedding_bag_cached(table, cache, slot_idx, cold_idx=None, *,
         rows = pl.pallas_call(
             functools.partial(_cache_gather_kernel, cache_rows=cache_rows,
                               nnz=nnz),
+            name="embedding_bag_cache",
             grid=(bp // bb,),
             in_specs=[
                 pl.BlockSpec((bb, nnz_lane), lambda b: (b, 0)),
@@ -246,6 +248,7 @@ def embedding_bag_cached(table, cache, slot_idx, cold_idx=None, *,
     rows = pl.pallas_call(
         functools.partial(_two_level_kernel, part_rows=part,
                           cache_rows=cache_rows, nnz=nnz),
+        name="embedding_bag_two_level",
         grid=(bp // bb, parts + 1),
         in_specs=[
             pl.BlockSpec((bb, nnz_lane), lambda b, p: (b, 0)),

@@ -120,6 +120,7 @@ def vocab_build_chunk(values, capacity: int, *, partitions: int = 1,
     out = pl.pallas_call(
         functools.partial(_build_kernel, part_size=part, width=width,
                           n_rows=rows),
+        name="vocab_build",
         grid=(partitions, rp // br),
         in_specs=[pl.BlockSpec((br, wp), lambda p, r: (r, 0))],
         out_specs=pl.BlockSpec((part_rows, lanes.LANE), lambda p, r: (p, 0)),
@@ -172,6 +173,7 @@ def vocab_lookup(x, table, n_unique, *, partitions: int = 1,
 
     out = pl.pallas_call(
         functools.partial(_lookup_kernel, part_size=part, cols=cols),
+        name="vocab_lookup",
         grid=(rp // br, partitions),
         in_specs=[
             pl.BlockSpec((br, bc), lambda r, p: (r, 0)),

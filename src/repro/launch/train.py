@@ -163,13 +163,12 @@ def main(argv=None):
             print(f"[train] done: {args.steps} steps, "
                   f"{toks/dt:,.0f} tok/s, etl_producer_wait="
                   f"{stats.producer_wait_s:.2f}s trainer_wait="
-                  f"{stats.consumer_wait_s:.2f}s "
-                  f"util={stats.trainer_utilization(dt - stats.consumer_wait_s):.2%}")
+                  f"{stats.consumer_wait_s:.2f}s")
             for name, s in stats.stage_breakdown().items():
                 print(f"[train]   stage {name:9s} items={s['items']:<5d} "
                       f"busy={s['busy_s']:.2f}s wait_in={s['wait_in_s']:.2f}s "
                       f"wait_out={s['wait_out_s']:.2f}s "
-                      f"occ={s['occupancy']:.1%}")
+                      f"occ={s['occupancy']:.1%} bytes={s['bytes']:,}")
             if args.metrics_file:
                 print(f"[train] metrics written to {args.metrics_file}")
             return final

@@ -3,7 +3,6 @@
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable
 
 import jax
@@ -40,9 +39,10 @@ def make_train_step(loss_fn: Callable, tcfg: TrainConfig,
                                      grad_specs=grad_specs)
 
     def train_step(state: TrainState, batch) -> tuple[TrainState, dict]:
-        loss, grads = vg(state.params, batch)
-        new_p, new_opt, gnorm = opt_update(grads, state.opt, state.params,
-                                           state.step, tcfg)
+        with jax.named_scope("train_step"):
+            loss, grads = vg(state.params, batch)
+            new_p, new_opt, gnorm = opt_update(grads, state.opt, state.params,
+                                               state.step, tcfg)
         new_state = TrainState(params=new_p, opt=new_opt,
                                step=state.step + 1)
         return new_state, {"loss": loss.astype(jnp.float32),
@@ -89,7 +89,7 @@ def jit_train_step(train_step, mesh, state_shapes, batch_shapes, *,
 
 
 # ---------------------------------------------------------------------------
-# driver loop: checkpoint/restart + watchdog + throughput accounting
+# training loop: checkpoint/restart + watchdog + step spans
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass
@@ -122,8 +122,6 @@ def train_loop(state: TrainState, step_fn, batches, loop_cfg: LoopConfig,
     etl_stats = getattr(batches, "stats", None)
     if embed_cache is not None and embed_tables is None:
         embed_tables = lambda params: params["tables"]
-    t0 = time.perf_counter()
-    train_s = 0.0
     try:
         for batch in batches:
             step_no = int(state.step)
@@ -133,10 +131,10 @@ def train_loop(state: TrainState, step_fn, batches, loop_cfg: LoopConfig,
                 batch = embed_cache.advance(embed_tables(state.params), batch)
             if wd:
                 wd.arm()
-            ts = time.perf_counter()
-            state, metrics = step_fn(state, batch)
-            jax.block_until_ready(metrics["loss"])
-            train_s += time.perf_counter() - ts
+            with jax.profiler.StepTraceAnnotation("train.step",
+                                                  step_num=step_no):
+                state, metrics = step_fn(state, batch)
+                jax.block_until_ready(metrics["loss"])
             if wd:
                 wd.check()
                 wd.disarm()
@@ -144,11 +142,8 @@ def train_loop(state: TrainState, step_fn, batches, loop_cfg: LoopConfig,
             if loop_cfg.log_every and step_no % loop_cfg.log_every == 0:
                 m = {k: float(v) for k, v in metrics.items()}
                 m["step"] = step_no
-                m["train_utilization"] = train_s / max(
-                    time.perf_counter() - t0, 1e-9)
                 if etl_stats is not None:
                     m["etl_starved_s"] = etl_stats.consumer_wait_s
-                    m["etl_overlapped_s"] = etl_stats.overlapped_etl_s
                     cache = getattr(etl_stats, "cache", None)
                     if cache is not None:
                         m["emb_cache_hit_rate"] = cache.hit_rate()

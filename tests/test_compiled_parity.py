@@ -16,6 +16,8 @@ Two tiers, gated by what this host can actually do:
   compile-only tier for a described v5e lives in ``test_tpu_compile.py``.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -190,6 +192,17 @@ CASES = [
     case_embedding_bag_cached, case_embedding_bag_cache_only,
 ]
 CASE_IDS = [c.__name__.removeprefix("case_") for c in CASES]
+#: the ``name=`` each case's ``pallas_call`` carries: the name of its op in
+#: the compiled program and in a device trace
+KERNEL_NAMES = {
+    "fused_stage": "etl_fused_stage", "fused_stage_hex": "etl_fused_stage",
+    "packer": "etl_pack", "output_dataflow": "etl_apply_output",
+    "group_dataflow": "etl_apply_group", "fit_dataflow": "etl_fit_build",
+    "vocab_build": "vocab_build", "vocab_lookup": "vocab_lookup",
+    "embedding_bag": "embedding_bag",
+    "embedding_bag_cached": "embedding_bag_two_level",
+    "embedding_bag_cache_only": "embedding_bag_cache",
+}
 
 
 def _as_arrays(out):
@@ -222,6 +235,17 @@ def test_compiled_trace_smoke(case):
     fn, args = case(interpret=False)
     out = jax.eval_shape(fn, *args)
     assert jax.tree_util.tree_leaves(out)
+
+
+@pytest.mark.parametrize("case", CASES, ids=CASE_IDS)
+def test_kernel_carries_its_name(case):
+    """Every ``pallas_call`` is named for its job: the name sits in the
+    interpret-mode lowering's op locations, as the compiled op's name does
+    (``test_tpu_compile.py``)."""
+    fn, args = case(interpret=True)
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    name = KERNEL_NAMES[case.__name__.removeprefix("case_")]
+    assert re.search(rf'["/]{name}/pallas_call"', text), name
 
 
 def test_fit_build_forms_bit_identical():

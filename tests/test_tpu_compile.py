@@ -17,6 +17,7 @@ cannot be read back on this host.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +25,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from test_compiled_parity import CASE_IDS, CASES
+from test_compiled_parity import CASE_IDS, CASES, KERNEL_NAMES
 
 REAL_ROWS = 65536
 
@@ -53,16 +54,23 @@ def _on(sharding, shape, dtype):
                                 sharding=sharding)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, name=None):
+    """Compile for the described chip; the Mosaic kernel must be kept, as
+    the op ``%<name>.<n>`` where its ``pallas_call`` is named."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()  # Mosaic kernel kept
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # Mosaic kernel kept
+    if name is not None:
+        assert re.search(rf"%{name}\.\d+ = .*custom_call_target="
+                         r'"tpu_custom_call"', text), name
     return compiled
 
 
 @pytest.mark.parametrize("case", CASES, ids=CASE_IDS)
 def test_kernel_compiles_for_v5e(one_chip, case):
     fn, args = case(interpret=False)
-    _compile(fn, *(_on(one_chip, a.shape, a.dtype) for a in args))
+    _compile(fn, *(_on(one_chip, a.shape, a.dtype) for a in args),
+             name=KERNEL_NAMES[case.__name__.removeprefix("case_")])
 
 
 @pytest.mark.parametrize("which,capacity", [("II", 131072), ("III", 524288)])
@@ -83,10 +91,12 @@ def test_main_path_compiles_at_real_size(one_chip, which, capacity, phase):
         resolved = {vid: _on(one_chip, (1, capacity), jnp.int32)
                     for vid in cp._resolved_tables()}
         compiled = _compile(cp._apply_fn, {}, {}, resolved,
-                            real(cp._raw_columns(raw)))
+                            real(cp._raw_columns(raw)),
+                            name="etl_apply_group")
     else:
         assert {r["path"] for r in cp.fit_lowering_report().values()} == {
             "fused"}
         compiled = _compile(cp._fit_chunk_fn,
-                            real(cp._raw_columns(raw, cp._fit_bufs)))
+                            real(cp._raw_columns(raw, cp._fit_bufs)),
+                            name="etl_fit_build")
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
