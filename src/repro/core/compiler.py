@@ -830,7 +830,12 @@ class CompiledPipeline:
         (HBM-resident vocab), "hex-terminal", "mosaic-illegal" (fits the
         logical budget but not the compiled lowering's lane-padded /
         gather-scratch one — interpret mode would fuse it), or "" when
-        the backend/fuse mode simply has no tile codegen.
+        the backend/fuse mode simply has no tile codegen.  A grouped or
+        fused output with an in-kernel lookup also carries
+        ``gather_entries_per_row`` (its lookup columns) and
+        ``gather_block`` (the entries its widest lookup issues per loop
+        step of ``lanes.lane_gather``: ``lanes.GATHER_ROWS`` rows of
+        them).
         """
         dfmap = {dp.output: dp for dp in self.plan.dataflows}
         groups = {name: self._active_groups[gi]
@@ -854,6 +859,13 @@ class CompiledPipeline:
                 "n_stages": dp.n_stages if dp else 0,
                 "vocab_ids": list(dp.vocab_ids) if dp else [],
             }
+            widths = [self.plan.buffers[st.in_buf].width
+                      for st in map(self.plan.stage_by_id,
+                                    dp.stage_ids if dp else [])
+                      if isinstance(st, VocabLookupStage)]
+            if path != "staged" and widths:
+                rep[po.name]["gather_entries_per_row"] = sum(widths)
+                rep[po.name]["gather_block"] = lanes.GATHER_ROWS * max(widths)
         return rep
 
     def fit_lowering_report(self) -> dict:

@@ -45,6 +45,10 @@ Vocabulary tables enter the dataflow kernel pre-resolved: the compiler folds
 the OOV rule (``miss -> n_unique``) into the table before the call, so the
 in-kernel lookup is a pure gather (``kernels.lanes.lane_gather``) from a
 table resident whole in VMEM in the row layout (``(capacity/128, 128)``).
+The gather walks a tile ``lanes.GATHER_ROWS`` rows per loop step with the
+lookup's columns unrolled: every lookup of a step is independent of the
+others, and the step stores its rows at once.  Its cost is O(entries),
+the same for every caller whatever the table's capacity.
 
 Tiling: every streamed block is lane-aligned — source and packed output
 blocks are padded up to multiples of 128 lanes host-side (padding lanes

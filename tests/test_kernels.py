@@ -4,9 +4,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import operators as O
-from repro.kernels import ops, ref
+from repro.kernels import lanes, ops, ref
 
 RNG = np.random.default_rng(7)
 HEXMAP = np.frombuffer(b"0123456789abcdef", np.uint8)
@@ -94,6 +96,58 @@ def test_vocab_lookup_sweep(rows, cols, cap, parts):
                                       partitions=parts, interpret=True))
     want = np.asarray(ref.vocab_lookup(jnp.asarray(x), jnp.asarray(table), n))
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.fixture(scope="module")
+def paper_ii_report():
+    from repro.core.pipeline import paper_pipeline
+    return paper_pipeline("II", small_vocab=512).compile(
+        backend="pallas", interpret=True).lowering_report()
+
+
+def _lane_gather(idx, flat):
+    """``lanes.lane_gather`` of ``idx`` through ``flat`` in one kernel."""
+    rows, w = idx.shape
+    wp = lanes.lane_pad(w)
+
+    def kernel(x_ref, t_ref, o_ref):
+        o_ref[:, :w] = lanes.lane_gather(t_ref, x_ref[...][:, :w])
+
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        kernel, in_specs=[vmem, vmem], out_specs=vmem,
+        out_shape=jax.ShapeDtypeStruct((rows, wp), jnp.int32),
+        interpret=True)(jnp.pad(jnp.asarray(idx), ((0, 0), (0, wp - w))),
+                        lanes.to_rows(jnp.asarray(flat)))
+    return np.asarray(out)[:, :w]
+
+
+# widths 1, 26 and 130 (two lane groups); rows of one block, a partial
+# block after a full one (13 = 8 + 5), a whole SMEM chunk (64), two chunks
+# (72) and two chunks then a partial block (77 = 72 + 5)
+@pytest.mark.parametrize("rows,width", [(8, 1), (13, 1), (64, 1), (72, 1),
+                                        (77, 1), (64, 26), (13, 26),
+                                        (8, 130)])
+def test_lane_gather_matches_take(rows, width, paper_ii_report):
+    cap, oov = 300, 7
+    rng = np.random.default_rng(rows * 1000 + width)
+    # full-range int32 entries, so both 16-bit halves of every lane matter;
+    # absent slots hold the OOV rank, as in a resolved vocabulary table
+    flat = rng.integers(-2 ** 31, 2 ** 31, size=cap, dtype=np.int64)
+    flat = flat.astype(np.int32)
+    absent = rng.choice(cap, size=20, replace=False)
+    flat[absent] = oov
+    assert (np.abs(flat.astype(np.int64)) > 1 << 16).sum() > cap // 2
+    idx = rng.integers(0, cap, size=(rows, width)).astype(np.int32)
+    edges = [0, 127, 128, cap - 1, absent[0]]
+    idx.reshape(-1)[:len(edges)] = edges[:idx.size]
+    idx[-1, -1] = cap - 1
+    np.testing.assert_array_equal(_lane_gather(idx, flat), np.take(flat, idx))
+    # the lowering report shows the block form of Pipeline II's lookup
+    rep = paper_ii_report["sparse"]
+    assert rep["path"] == "grouped"
+    assert rep["gather_entries_per_row"] == 26
+    assert rep["gather_block"] == 26 * lanes.GATHER_ROWS == 208
 
 
 @pytest.mark.parametrize("widths,out_dtype", [
